@@ -26,6 +26,7 @@ from repro.core import (
     sti_knn_interactions, knn_shapley_values, loo_values, analysis)
 from repro.core.sti_baseline import brute_force_sti
 from repro.data import make_circles, make_moons, flip_labels
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, reps=3, **kw):
@@ -356,106 +357,111 @@ def bench_diag_hoist():
 
 
 # ------------------------------------------------------------ sharded:
-# the multi-device fused pipeline, measured under forced host devices so the
-# scaling rows exist on CPU-only hosts too (genuine speedups need real
-# accelerators; what CPU rows track is overhead + the n^2/D memory split).
+# the multi-device fused pipeline. On an accelerator the phase runs in this
+# process over the local devices (a child process could not get the chip
+# this process already holds); on CPU it runs in a child with forced host
+# devices, so the scaling rows exist on CPU-only hosts too (what CPU rows
+# track is overhead + the n^2/D memory split, not speed).
+_SHARDED_SHAPE = dict(n=512, t=64, k=5, tb=32)
+
+
+def _sharded_measure(n, t, k, tb):
+    """Time the fused and sharded steps at (n, t, k, tb) and, off-TPU, the
+    rect XLA block scan against the rect Pallas kernel in interpret mode
+    (on TPU Mosaic refuses that kernel's gather -- ROADMAP S1 -- so the row
+    is not offered). Returns a dict of the measured numbers."""
+    from repro.kernels.sti_pipeline import (
+        fused_sti_knn_interactions, sharded_sti_knn_interactions)
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(n, 16)).astype(np.float32))
+    y = jnp.asarray(rng.integers(0, 2, n).astype(np.int32))
+    xt = jnp.asarray(rng.normal(size=(t, 16)).astype(np.float32))
+    yt = jnp.asarray(rng.integers(0, 2, t).astype(np.int32))
+    out = {"devices": jax.device_count()}
+    kw = dict(test_batch=tb, fill="chunked", fill_params={"chunk": 1},
+              distance="xla")
+    out["us_fused"] = _time(lambda: fused_sti_knn_interactions(
+        x, y, xt, yt, k, **kw))
+    out["us_shard"] = _time(lambda: sharded_sti_knn_interactions(
+        x, y, xt, yt, k, **kw))
+    out["err"] = float(jnp.max(jnp.abs(
+        fused_sti_knn_interactions(x, y, xt, yt, k, test_batch=tb)
+        - sharded_sti_knn_interactions(x, y, xt, yt, k, test_batch=tb))))
+    if jax.default_backend() == "tpu":
+        return out
+    # small shape: the Pallas row runs in INTERPRET mode (Python-speed)
+    nr, tr, tbr = 256, 16, 16
+    xr = jnp.asarray(rng.normal(size=(nr, 16)).astype(np.float32))
+    yr = jnp.asarray(rng.integers(0, 2, nr).astype(np.int32))
+    xtr = jnp.asarray(rng.normal(size=(tr, 16)).astype(np.float32))
+    ytr = jnp.asarray(rng.integers(0, 2, tr).astype(np.int32))
+
+    def rect(fill):
+        return sharded_sti_knn_interactions(
+            xr, yr, xtr, ytr, k, test_batch=tbr, fill=fill, distance="xla")
+
+    out.update(nr=nr, tr=tr, us_rect_scan=_time(lambda: rect("chunked")),
+               us_rect_pal=_time(lambda: rect("pallas")),
+               err_rect=float(jnp.max(jnp.abs(rect("chunked")
+                                              - rect("pallas")))))
+    return out
+
+
 def bench_sharded():
     import os
     import subprocess
     import sys
     from pathlib import Path
 
-    n, t, k, tb, devices = 512, 64, 5, 32, 8
-    code = f"""
-import time
-import numpy as np, jax, jax.numpy as jnp
-import repro
-from repro.kernels.sti_pipeline import (
-    fused_sti_knn_interactions, sharded_sti_knn_interactions)
-
-rng = np.random.default_rng(0)
-n, t, k, tb = {n}, {t}, {k}, {tb}
-x = jnp.asarray(rng.normal(size=(n, 16)).astype(np.float32))
-y = jnp.asarray(rng.integers(0, 2, n).astype(np.int32))
-xt = jnp.asarray(rng.normal(size=(t, 16)).astype(np.float32))
-yt = jnp.asarray(rng.integers(0, 2, t).astype(np.int32))
-
-def timeit(fn):
-    jax.block_until_ready(fn())  # compile + warmup
-    t0 = time.perf_counter()
-    for _ in range(3):
-        out = fn()
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / 3 * 1e6
-
-us_fused = timeit(lambda: fused_sti_knn_interactions(
-    x, y, xt, yt, k, test_batch=tb, fill="chunked",
-    fill_params={{"chunk": 1}}, distance="xla"))
-us_shard = timeit(lambda: sharded_sti_knn_interactions(
-    x, y, xt, yt, k, test_batch=tb, fill="chunked",
-    fill_params={{"chunk": 1}}, distance="xla"))
-err = float(jnp.max(jnp.abs(
-    fused_sti_knn_interactions(x, y, xt, yt, k, test_batch=tb)
-    - sharded_sti_knn_interactions(x, y, xt, yt, k, test_batch=tb))))
-print(f"ROW,{{jax.device_count()}},{{us_fused:.1f}},{{us_shard:.1f}},{{err:.2e}}")
-
-# rect-fill comparison: the sharded local row-block update through the XLA
-# block scan vs the rectangular Pallas accumulate kernel. Off-TPU the Pallas
-# row runs in INTERPRET mode (correctness trend only, Python-speed) at a
-# small shape; on TPU the same two rows measure the real kernel.
-nr, tr, tbr = ({n}, {t}, {tb}) if jax.default_backend() == "tpu" else (256, 16, 16)
-xr = jnp.asarray(rng.normal(size=(nr, 16)).astype(np.float32))
-yr = jnp.asarray(rng.integers(0, 2, nr).astype(np.int32))
-xtr = jnp.asarray(rng.normal(size=(tr, 16)).astype(np.float32))
-ytr = jnp.asarray(rng.integers(0, 2, tr).astype(np.int32))
-us_rect_scan = timeit(lambda: sharded_sti_knn_interactions(
-    xr, yr, xtr, ytr, k, test_batch=tbr, fill="chunked", distance="xla"))
-us_rect_pal = timeit(lambda: sharded_sti_knn_interactions(
-    xr, yr, xtr, ytr, k, test_batch=tbr, fill="pallas", distance="xla"))
-err_rect = float(jnp.max(jnp.abs(
-    sharded_sti_knn_interactions(xr, yr, xtr, ytr, k, test_batch=tbr,
-                                 fill="chunked", distance="xla")
-    - sharded_sti_knn_interactions(xr, yr, xtr, ytr, k, test_batch=tbr,
-                                   fill="pallas", distance="xla"))))
-print(f"RECT,{{nr}},{{tr}},{{us_rect_scan:.1f}},{{us_rect_pal:.1f}},{{err_rect:.2e}}")
-"""
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-        PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"),
-    )
-    p = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=900)
-    if p.returncode != 0:
-        return [("sharded_subprocess_failed", 0.0,
-                 (p.stderr.strip().splitlines() or [""])[-1][:120],
-                 {"method": "sti", "engine": "sharded"})]
-    dev, us_fused, us_shard, err = [
-        ln for ln in p.stdout.splitlines() if ln.startswith("ROW,")
-    ][0].split(",")[1:]
-    nr, tr, us_rect_scan, us_rect_pal, err_rect = [
-        ln for ln in p.stdout.splitlines() if ln.startswith("RECT,")
-    ][0].split(",")[1:]
-    dev = int(dev)
+    shape = _SHARDED_SHAPE
+    n, t = shape["n"], shape["t"]
+    if jax.default_backend() != "cpu":
+        m = _sharded_measure(**shape)
+    else:
+        root = Path(__file__).resolve().parents[1]
+        env = dict(
+            os.environ,
+            XLA_FLAGS="--xla_force_host_platform_device_count=8",
+            PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]),
+        )
+        code = ("import json; from benchmarks.run import _sharded_measure, "
+                "_SHARDED_SHAPE as s; "
+                "print('RESULT', json.dumps(_sharded_measure(**s)))")
+        p = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                           capture_output=True, text=True, timeout=900)
+        if p.returncode != 0:
+            raise RuntimeError(
+                f"sharded bench child failed (rc={p.returncode}):\n"
+                f"{p.stderr[-4000:]}"
+            )
+        (line,) = [ln for ln in p.stdout.splitlines()
+                   if ln.startswith("RESULT ")]
+        m = json.loads(line[len("RESULT "):])
+    dev = int(m["devices"])
     per_dev_mb = n * n * 4 / dev / 2**20
-    pal_mode = ("compiled" if jax.default_backend() == "tpu"
-                else "interpret (correctness only; perf target is TPU)")
-    return [
-        (f"sti_fused_1dev_n{n}_t{t}", float(us_fused),
+    rows = [
+        (f"sti_fused_1dev_n{n}_t{t}", float(m["us_fused"]),
          f"acc_mem={n*n*4/2**20:.1f}MiB",
          {"method": "sti", "engine": "fused"}),
-        (f"sti_sharded_{dev}dev_n{n}_t{t}", float(us_shard),
-         f"acc_mem_per_dev={per_dev_mb:.2f}MiB;max_err_vs_fused={err};"
-         f"forced_host_devices={dev}",
+        (f"sti_sharded_{dev}dev_n{n}_t{t}", float(m["us_shard"]),
+         f"acc_mem_per_dev={per_dev_mb:.2f}MiB;"
+         f"max_err_vs_fused={m['err']:.2e};devices={dev}",
          {"method": "sti", "engine": "sharded"}),
-        (f"sti_sharded_{dev}dev_xla_scan_fill_n{nr}_t{tr}",
-         float(us_rect_scan), "fill=rect_chunked(XLA block scan)",
-         {"method": "sti", "engine": "sharded", "fill": "chunked"}),
-        (f"sti_sharded_{dev}dev_pallas_fill_n{nr}_t{tr}",
-         float(us_rect_pal),
-         f"fill=rect_pallas({pal_mode});max_err_vs_scan={err_rect}",
-         {"method": "sti", "engine": "sharded", "fill": "pallas"}),
     ]
+    if "us_rect_pal" in m:
+        nr, tr = m["nr"], m["tr"]
+        rows += [
+            (f"sti_sharded_{dev}dev_xla_scan_fill_n{nr}_t{tr}",
+             float(m["us_rect_scan"]), "fill=rect_chunked(XLA block scan)",
+             {"method": "sti", "engine": "sharded", "fill": "chunked"}),
+            (f"sti_sharded_{dev}dev_pallas_fill_n{nr}_t{tr}",
+             float(m["us_rect_pal"]),
+             f"fill=rect_pallas(interpret; correctness only);"
+             f"max_err_vs_scan={m['err_rect']:.2e}",
+             {"method": "sti", "engine": "sharded", "fill": "pallas"}),
+        ]
+    return rows
 
 
 # ------------------------------------------------------------- service:
@@ -757,6 +763,7 @@ def main() -> None:
                          "platform-keyed autotune cache BEFORE timing, and "
                          "emit one row per tuned winner")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.json_path:
         args.json = True
     args.json_path = args.json_path or "BENCH_sti_knn.json"

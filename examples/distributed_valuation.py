@@ -18,6 +18,7 @@ from repro import compat
 from repro.configs.sti_knn_paper import STIConfig
 from repro.core import sti_knn_interactions
 from repro.data import make_moons
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.specs import sti_cell
 
 n, t, k = 512, 128, 5
@@ -26,7 +27,7 @@ xt, yt = make_moons(t // 2, noise=0.08, seed=1)
 
 devs = len(jax.devices())
 dmodel = 2 if devs % 2 == 0 else 1
-mesh = jax.make_mesh((devs // dmodel, dmodel), ("data", "model"))
+mesh = make_auto_mesh((devs // dmodel, dmodel), ("data", "model"))
 print(f"devices: {devs}, mesh: {dict(mesh.shape)}")
 
 scfg = STIConfig(n_train=n, feat_dim=2, k=k, test_chunk=t)

@@ -2,9 +2,8 @@
 
 R101: a variable passed in a donated position of a jitted call is read
 again after the call without being rebound to the call's result. Donation
-invalidates the input buffer (`donate_argnums`): off-CPU the old array is
-deleted and any later use raises (or worse, silently reads garbage under
-some backends/versions) — the streaming-session contract in this repo is
+invalidates the input buffer (`donate_argnums`): the old array is
+deleted and any later use raises — the streaming-session contract in this repo is
 always `state = step(state, ...)`.
 """
 

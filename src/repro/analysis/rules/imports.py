@@ -12,6 +12,10 @@ R602: device-topology probes at import time (`jax.devices()`,
       pin the process to whatever topology existed at import, breaking
       late `XLA_FLAGS`/mesh configuration (the multi-device CI forces 8
       host devices AFTER deciding to import).
+R603: process-wide JAX configuration at import time (`jax.config.update`,
+      `enable_compile_cache`): importing a module must not move every
+      other caller's compile cache or flags; entry points set them at
+      start-up (`repro.launch.compile_cache`).
 """
 
 from __future__ import annotations
@@ -29,6 +33,12 @@ from repro.analysis.rules import (
 _DEVICE_PROBES = {
     "jax.devices", "jax.device_count", "jax.local_devices",
     "jax.local_device_count", "jax.default_backend", "jax.process_index",
+}
+
+
+_PROCESS_CONFIG = {
+    "jax.config.update", "enable_compile_cache",
+    "compile_cache.enable_compile_cache",
 }
 
 
@@ -97,4 +107,20 @@ def check_device_probe(ctx: ModuleContext) -> Iterator[Finding]:
                     f"time: forces backend init before XLA_FLAGS/mesh "
                     f"setup can happen",
                     "probe inside the function that needs the topology",
+                )
+
+
+@rule("R603", "process-config-at-import")
+def check_config_at_import(ctx: ModuleContext) -> Iterator[Finding]:
+    """Module-scope JAX configuration changes every importer's process."""
+    for expr in _module_level_exprs(ctx.tree):
+        for sub in ast.walk(expr):
+            if isinstance(sub, ast.Call) and \
+                    dotted_name(sub.func) in _PROCESS_CONFIG:
+                yield ctx.finding(
+                    "R603", sub,
+                    f"'{dotted_name(sub.func)}()' at import time changes "
+                    f"the JAX configuration of every process that imports "
+                    f"this module",
+                    "call it at start-up, inside the entry point's main()",
                 )

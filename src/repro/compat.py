@@ -1,12 +1,9 @@
-"""jax API compatibility shims.
+"""One place for the mesh / shard_map spellings the codebase uses.
 
-The codebase is written against the modern mesh/shard_map spellings
-(`jax.shard_map`, `jax.set_mesh`, `jax.sharding.get_abstract_mesh`), but the
-container pins jax 0.4.37, which only has
-`jax.experimental.shard_map.shard_map(..., check_rep=...)` and the
-`with mesh:` thread-local context (no ambient abstract mesh). Every caller
-routes through this module so the version split lives in exactly one place;
-on a new-enough jax the shims are pass-throughs.
+The code is written for the installed jax (see requirements.txt), whose
+spellings are `jax.shard_map`, `jax.set_mesh` and
+`jax.sharding.get_abstract_mesh`. Callers route through this module so a
+future API change is made here once:
 
     from repro import compat
     step = compat.shard_map(f, mesh=mesh, in_specs=..., out_specs=...,
@@ -21,66 +18,29 @@ from typing import Optional
 
 import jax
 
-__all__ = ["shard_map", "set_mesh", "get_mesh", "HAS_NATIVE_SHARD_MAP"]
-
-# jax >= 0.5-era spellings present?
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
+__all__ = ["shard_map", "set_mesh", "get_mesh"]
 
 
 def get_mesh():
-    """The ambient mesh, or None.
-
-    New jax: the abstract mesh installed by `jax.set_mesh`. Old jax: the
-    thread-local physical mesh installed by `with mesh:` (which is what
-    `set_mesh` below enters on 0.4.x).
-    """
-    if _HAS_ABSTRACT_MESH:
-        m = jax.sharding.get_abstract_mesh()
-        return None if m is None or not m.axis_names else m
-    from jax.interpreters import pxla
-
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The ambient mesh installed by `set_mesh`, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or not m.axis_names else m
 
 
 def set_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    `jax.set_mesh(mesh)` on new jax; on 0.4.x a concrete `Mesh` is itself a
-    context manager that sets the thread-local mesh `shard_map` (below) and
-    sharding-constraint machinery consult.
-    """
-    if _HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return mesh  # Mesh.__enter__ / __exit__ manage thread_resources
+    """Context manager installing `mesh` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh=None, *, in_specs, out_specs,
               check_vma: Optional[bool] = None):
-    """`jax.shard_map` with the modern keyword surface on any jax.
+    """`jax.shard_map` with an optional explicit mesh.
 
     Args:
       mesh: explicit mesh; None uses the ambient mesh (`set_mesh` context).
-      check_vma: the new-jax replication-checking flag; mapped onto the old
-        spelling `check_rep` on 0.4.x. None keeps each version's default.
+      check_vma: jax's replication-checking flag; None keeps its default.
     """
-    if HAS_NATIVE_SHARD_MAP:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        if mesh is not None:
-            kw["mesh"] = mesh
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        mesh = get_mesh()
-        if mesh is None:
-            raise ValueError(
-                "compat.shard_map needs a mesh: pass mesh= or enter a "
-                "compat.set_mesh(mesh) context first"
-            )
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    if mesh is not None:
+        kw["mesh"] = mesh
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs, **kw)

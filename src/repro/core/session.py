@@ -402,8 +402,8 @@ class ShardedValuationSession(ValuationSession):
             )
         )
         self.test_batch = int(self._resolved["test_batch"])
-        self._place_state(
-            tuple(np.zeros(s, np.float32) for s in self._spec.shapes(n))
+        self._state = self._spec.init(
+            n, self._spec.shardings(self.mesh, self.mesh.axis_names[0])
         )
         from repro.distributed.sharding import replicated_sharding
 
@@ -444,9 +444,10 @@ class ShardedValuationSession(ValuationSession):
             return
         axis = self.mesh.axis_names[0]
         shardings = self._spec.shardings(self.mesh, axis)
+        # host arrays go straight to their shards: jnp.asarray first would
+        # put the whole (n, n) array on one device
         self._state = tuple(
-            jax.device_put(jnp.asarray(a), s)
-            for a, s in zip(arrays, shardings)
+            jax.device_put(a, s) for a, s in zip(arrays, shardings)
         )
 
     def _gathered_state(self) -> tuple:

@@ -159,10 +159,14 @@ def superdiagonal_g_topm(
 
 def pairwise_sq_dists(x_test: jnp.ndarray, x_train: jnp.ndarray) -> jnp.ndarray:
     """(t, d), (n, d) -> (t, n) squared L2 distances via the MXU-friendly
-    expansion ||a-b||^2 = ||a||^2 - 2 a.b + ||b||^2 (f32 accumulation)."""
+    expansion ||a-b||^2 = ||a||^2 - 2 a.b + ||b||^2.
+
+    The cross term runs at `Precision.HIGHEST` (f32-exact products): the
+    TPU default rounds f32 operands to bf16, which reorders near-tied
+    neighbours and so changes the exact ranks the valuation depends on."""
     xt = x_test.astype(jnp.float32)
     xn = x_train.astype(jnp.float32)
-    cross = xt @ xn.T
+    cross = jnp.matmul(xt, xn.T, precision=jax.lax.Precision.HIGHEST)
     d2 = (
         jnp.sum(xt * xt, -1, keepdims=True)
         - 2.0 * cross
@@ -234,29 +238,32 @@ def _scan_fill(one_fn: Callable, g, ranks, chunk: int, acc0=None) -> jnp.ndarray
     return acc
 
 
-def _chunked_one(n: int) -> Callable:
-    idx = jnp.arange(n)
+def _select_one(g_p, rr_p, rc_p):
+    """Per-test-point (n_rows, n_cols) block g[max(rr[a], rc[b])] as a
+    broadcast select with no 2-D gather:
 
-    def one(g_p, r_p):
-        m_sorted = jnp.where(idx[None, :] >= idx[:, None], g_p[None, :], g_p[:, None])
-        return m_sorted[r_p][:, r_p]
+        g[max(i, j)] = where(j >= i, g[j], g[i])
 
-    return one
+    so only the two (n,)-sized gathers g[rr] and g[rc] exist, and XLA fuses
+    the select with the accumulator add: the block is never a temporary."""
+    return jnp.where(rc_p[None, :] >= rr_p[:, None],
+                     g_p[rc_p][None, :], g_p[rr_p][:, None])
+
+
+def _square_select_one(g_p, r_p):
+    """`_select_one` with one rank vector for rows and columns."""
+    return _select_one(g_p, r_p, r_p)
 
 
 def _fill_chunked(g: jnp.ndarray, ranks: jnp.ndarray, *, chunk: int = 1) -> jnp.ndarray:
-    """Chunked scan fill: constant memory in t (peak O(chunk * n^2)).
+    """Chunked scan fill: constant memory in t.
 
-    Per test point the matrix in *sorted* coordinates is
-        M[i, j] = g[max(i, j)] = where(j >= i, g[j], g[i])
-    (a broadcasted select, no gather), and the train-coordinate matrix is the
-    row/column permutation M[r_p][:, r_p]. A lax.scan streams `chunk` test
-    points at a time into the (n, n) f32 accumulator, so nothing of size
-    O(t n^2) ever exists -- this is the default fill (EXPERIMENTS.md
-    "Fill variants" measures it 2-3x faster than `_fill_xla` on CPU at
-    t=64, n=2048 on top of the memory win).
+    Per test point the train-coordinate matrix is the broadcast select
+    `_select_one` (no (n, n) index or gather temporary). A lax.scan streams
+    `chunk` test points at a time into the (n, n) f32 accumulator, so
+    nothing of size O(t n^2) ever exists -- this is the default fill.
     """
-    return _scan_fill(_chunked_one(g.shape[-1]), g, ranks, chunk)
+    return _scan_fill(_square_select_one, g, ranks, chunk)
 
 
 def _onehot_one(n: int) -> Callable:
@@ -268,6 +275,7 @@ def _onehot_one(n: int) -> Callable:
         return jax.lax.dot_general(
             c * dg[None, :], c, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     return one
@@ -290,7 +298,7 @@ def _fill_onehot(g: jnp.ndarray, ranks: jnp.ndarray, *, chunk: int = 1) -> jnp.n
 def _acc_fill_chunked(acc, g, ranks, *, chunk: int = 1) -> jnp.ndarray:
     """In-place form of the chunked fill: the scan carry is the caller's
     accumulator, so no second (n, n) temporary exists."""
-    return _scan_fill(_chunked_one(g.shape[-1]), g, ranks, chunk, acc0=acc)
+    return _scan_fill(_square_select_one, g, ranks, chunk, acc0=acc)
 
 
 def _acc_fill_onehot(acc, g, ranks, *, chunk: int = 1) -> jnp.ndarray:
@@ -310,7 +318,8 @@ def _rect_one(g_p, rr_p, rc_p):
 
 def _rect_fill_xla(g, r_rows, r_cols) -> jnp.ndarray:
     """Rectangular reference fill: materializes the (t, n_rows, n_cols)
-    gather. Correctness oracle for the streaming/Pallas rect variants."""
+    gather. Correctness oracle for the streaming/Pallas rect variants (it
+    keeps the literal max-gather, independent of `_select_one`)."""
     return jnp.sum(jax.vmap(_rect_one)(g, r_rows, r_cols), axis=0)
 
 
@@ -331,7 +340,7 @@ def _scan_rect_fill(g, r_rows, r_cols, chunk: int, acc0=None) -> jnp.ndarray:
 
     def body(acc, batch):
         gc, rrc, rcc = batch
-        return acc + jnp.sum(jax.vmap(_rect_one)(gc, rrc, rcc), axis=0), None
+        return acc + jnp.sum(jax.vmap(_select_one)(gc, rrc, rcc), axis=0), None
 
     acc, _ = jax.lax.scan(
         body,
@@ -346,8 +355,9 @@ def _scan_rect_fill(g, r_rows, r_cols, chunk: int, acc0=None) -> jnp.ndarray:
 
 
 def _rect_fill_chunked(g, r_rows, r_cols, *, chunk: int = 1) -> jnp.ndarray:
-    """Chunked rect scan fill: constant memory in t, peak
-    O(chunk * n_rows * n_cols). The sharded engine's XLA fallback path."""
+    """Chunked rect scan fill: constant memory in t, per-point blocks as
+    the fused broadcast select `_select_one`. The sharded engine's default
+    row-block fill."""
     return _scan_rect_fill(g, r_rows, r_cols, chunk)
 
 
@@ -499,9 +509,10 @@ def resolve_rect_fill(
     "auto" consults the autotune cache under the rectangular key (the
     `rows{R}` segment alongside backend/device-count/size buckets); a miss
     runs the tuner (autotune=True) or falls back to the backend heuristic.
-    A Pallas request on a build where the Pallas rect kernels never
-    registered falls back to the XLA block scan ("chunked") instead of
-    failing -- the sharded engine must run everywhere.
+    A SQUARE registry name with no rect twin (e.g. "onehot" restored from a
+    single-device checkpoint) runs the XLA block scan, with a warning.
+    Every other unregistered name raises: a Pallas request is never
+    quietly replaced by another fill.
     """
     params = dict(fill_params or {})
     if fill == "auto":
@@ -512,29 +523,21 @@ def resolve_rect_fill(
         params = _accepted_params(_RECT_FILL_FNS[name], tuned)
         fill = name
     if fill not in _RECT_FILL_FNS:
-        if fill.startswith("pallas") or fill in _FILL_FNS:
-            # two legitimate misses, both resolved to the XLA block scan:
-            # a Pallas request on a build where the kernels never imported,
-            # and a SQUARE registry name with no rect twin (e.g. "onehot"
-            # restored from a single-device checkpoint) -- the sharded
-            # engine must keep running in both cases.
-            if fill in _FILL_FNS and fill not in ("pallas",
-                                                  "pallas_interpret"):
-                import warnings
-
-                warnings.warn(
-                    f"fill {fill!r} has no rectangular variant; the "
-                    f"sharded engine runs the XLA block scan instead",
-                    stacklevel=2,
-                )
-            fill, params = "chunked", _accepted_params(
-                _RECT_FILL_FNS["chunked"], params
-            )
-        else:
+        if fill not in _FILL_FNS:
             raise ValueError(
                 f"unknown rect fill {fill!r}; registered: "
                 f"{sorted(_RECT_FILL_FNS)}"
             )
+        import warnings
+
+        warnings.warn(
+            f"fill {fill!r} has no rectangular variant; the "
+            f"sharded engine runs the XLA block scan instead",
+            stacklevel=2,
+        )
+        fill, params = "chunked", _accepted_params(
+            _RECT_FILL_FNS["chunked"], params
+        )
     bad = set(params) - set(_accepted_params(_RECT_FILL_FNS[fill], params))
     if bad:
         raise ValueError(

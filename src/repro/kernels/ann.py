@@ -255,7 +255,8 @@ def full_mean_sq_dist(xb: jnp.ndarray, tables: LSHTables) -> jnp.ndarray:
     x = xb.astype(jnp.float32)
     mean_d2 = (
         jnp.sum(x * x, axis=-1, keepdims=True)
-        - 2.0 * (x @ tables.train_mean[:, None])
+        - 2.0 * jnp.matmul(x, tables.train_mean[:, None],
+                           precision=jax.lax.Precision.HIGHEST)
         + tables.mean_sq_norm
     )
     return jnp.maximum(mean_d2, 0.0)

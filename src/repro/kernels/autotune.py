@@ -22,6 +22,7 @@ from __future__ import annotations
 import functools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -36,14 +37,17 @@ __all__ = [
     "clear_cache",
     "device_platform",
     "fill_candidates",
+    "default_fill",
     "autotune_fill",
     "lookup_fill",
     "best_fill",
     "rect_fill_candidates",
+    "default_rect_fill",
     "autotune_rect_fill",
     "lookup_rect_fill",
     "best_rect_fill",
     "distance_candidates",
+    "default_distance",
     "autotune_distance",
     "best_distance",
     "ann_candidates",
@@ -185,6 +189,17 @@ def _key(kind: str, backend: str, n: int, t: int,
     return f"{kind}:{backend}:{plat}:dev{d}:{r}n{_bucket(n)}:t{_bucket(t)}"
 
 
+def _candidate_failed(failed: dict, label: str, err: Exception) -> None:
+    """Record and print a candidate that failed to compile or run.
+
+    A refused candidate is a finding about the platform (e.g. a Pallas
+    kernel Mosaic cannot lower), so it is kept in the cache entry's
+    "failed" map with its error and printed, never dropped in silence."""
+    msg = f"{type(err).__name__}: {err}".splitlines()[0][:400]
+    failed[label] = msg
+    print(f"autotune: candidate {label} failed: {msg}", file=sys.stderr)
+
+
 def _time_call(fn, *args, reps: int = 2) -> float:
     out = fn(*args)  # compile + warmup
     jax.block_until_ready(out)
@@ -227,10 +242,11 @@ def fill_candidates(n: int, t: int, backend: str) -> list[tuple[str, dict]]:
 
 
 def default_fill(backend: str) -> tuple[str, dict]:
-    """Backend heuristic on a cache miss: Pallas on TPU, chunked scan
-    (chunk=1) elsewhere."""
-    if backend == "tpu":
-        return "pallas", {}
+    """Heuristic on a cache miss: the chunked XLA scan (chunk=1) on every
+    backend. The Pallas fill is not the TPU default because Mosaic refuses
+    its per-test-point gather (`_tile_sum`: a dynamic_slice of the rank
+    tile, then a gather from the (n,) g row that is not 2-D); it stays a
+    tuner candidate there, and a failing compile is recorded."""
     return "chunked", {"chunk": 1}
 
 
@@ -250,15 +266,18 @@ def autotune_fill(
     ts = int(min(max(1, t), _SAMPLE_T))
     g, ranks = _synthetic_fill_problem(n, ts)
     timings: dict[str, float] = {}
+    failed: dict[str, str] = {}
     for name, params in fill_candidates(n, ts, backend):
         if name not in _FILL_FNS:
             continue
+        label = f"{name} {json.dumps(params, sort_keys=True)}"
         fn = jax.jit(functools.partial(_FILL_FNS[name], **params))
         try:
             us = _time_call(fn, g, ranks, reps=reps)
-        except Exception:  # candidate unsupported on this backend
+        except Exception as e:  # noqa: BLE001 -- recorded, not dropped
+            _candidate_failed(failed, label, e)
             continue
-        timings[f"{name} {json.dumps(params, sort_keys=True)}"] = us
+        timings[label] = us
         if verbose:
             print(f"autotune fill n={n} t={t} {name} {params}: {us:.0f}us")
     if not timings:
@@ -272,6 +291,7 @@ def autotune_fill(
         "us": timings[winner],
         "sample_t": ts,
         "candidates": timings,
+        "failed": failed,
     }
     with _LOCK:
         # copy: never mutate the _MEMO-shared dict before _save succeeds.
@@ -312,10 +332,7 @@ def best_fill(
         return hit
     if allow_tune:
         return autotune_fill(n, t, backend=backend, path=path)
-    name, params = default_fill(backend)
-    if name not in _FILL_FNS:  # pallas not registered: fall back to chunked
-        name, params = "chunked", {"chunk": 1}
-    return name, params
+    return default_fill(backend)
 
 
 # -------------------------------------------------------------- rect fill --
@@ -347,10 +364,9 @@ def rect_fill_candidates(rows: int, n: int, t: int,
 
 
 def default_rect_fill(backend: str) -> tuple[str, dict]:
-    """Backend heuristic on a cache miss: the Pallas rect kernel on TPU,
-    the XLA block scan elsewhere."""
-    if backend == "tpu":
-        return "pallas", {}
+    """Heuristic on a cache miss: the XLA block scan on every backend (the
+    Pallas rect kernel shares the square kernel's `_tile_sum`, which Mosaic
+    refuses; see `default_fill`)."""
     return "chunked", {"chunk": 1}
 
 
@@ -377,15 +393,18 @@ def autotune_rect_fill(
     ts = int(min(max(1, t), _SAMPLE_T))
     g, r_rows, r_cols = _synthetic_rect_fill_problem(rows, n, ts)
     timings: dict[str, float] = {}
+    failed: dict[str, str] = {}
     for name, params in rect_fill_candidates(rows, n, ts, backend):
         if name not in _RECT_FILL_FNS:
             continue
+        label = f"{name} {json.dumps(params, sort_keys=True)}"
         fn = jax.jit(functools.partial(_RECT_FILL_FNS[name], **params))
         try:
             us = _time_call(fn, g, r_rows, r_cols, reps=reps)
-        except Exception:  # candidate unsupported on this backend
+        except Exception as e:  # noqa: BLE001 -- recorded, not dropped
+            _candidate_failed(failed, label, e)
             continue
-        timings[f"{name} {json.dumps(params, sort_keys=True)}"] = us
+        timings[label] = us
         if verbose:
             print(f"autotune rect fill rows={rows} n={n} t={t} "
                   f"{name} {params}: {us:.0f}us")
@@ -400,6 +419,7 @@ def autotune_rect_fill(
         "us": timings[winner],
         "sample_t": ts,
         "candidates": timings,
+        "failed": failed,
     }
     with _LOCK:
         data = dict(_load(path))
@@ -439,10 +459,7 @@ def best_rect_fill(
         return hit
     if allow_tune:
         return autotune_rect_fill(rows, n, t, backend=backend, path=path)
-    name, params = default_rect_fill(backend)
-    if name not in _RECT_FILL_FNS:  # pallas not registered: XLA block scan
-        name, params = "chunked", {"chunk": 1}
-    return name, params
+    return default_rect_fill(backend)
 
 
 # ------------------------------------------------------------- distance ----
@@ -482,16 +499,19 @@ def autotune_distance(
     xt = jnp.asarray(rng.normal(size=(min(t, 256), d)).astype(np.float32))
     xn = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     timings: dict[str, float] = {}
+    failed: dict[str, str] = {}
     for name, params in cands:
+        label = f"{name} {json.dumps(params, sort_keys=True)}"
         if name == "xla":
             fn = jax.jit(pairwise_sq_dists)
         else:
             fn = functools.partial(distance_pallas, **params)
         try:
             us = _time_call(fn, xt, xn, reps=reps)
-        except Exception:
+        except Exception as e:  # noqa: BLE001 -- recorded, not dropped
+            _candidate_failed(failed, label, e)
             continue
-        timings[f"{name} {json.dumps(params, sort_keys=True)}"] = us
+        timings[label] = us
     if not timings:
         return "xla", {}
     winner = min(timings, key=timings.get)
@@ -501,7 +521,7 @@ def autotune_distance(
         data = dict(_load(path))
         data[_key(f"distance_d{d}", backend, n, t)] = {
             "distance": name, "params": params,
-            "us": timings[winner], "candidates": timings,
+            "us": timings[winner], "candidates": timings, "failed": failed,
         }
         _save(path, data)
     return name, params
@@ -524,6 +544,13 @@ def best_distance(
         return str(entry["distance"]), dict(entry.get("params") or {})
     if allow_tune:
         return autotune_distance(t, n, d, backend=backend, path=path)
+    return default_distance(backend)
+
+
+def default_distance(backend: str) -> tuple[str, dict]:
+    """Heuristic on a cache miss: the Pallas distance kernel on TPU (its
+    norms are 2-D blocks, the layout Mosaic accepts), the XLA expansion
+    elsewhere (interpret-mode Pallas is Python-speed)."""
     return ("pallas", {}) if backend == "tpu" else ("xla", {})
 
 
@@ -587,6 +614,7 @@ def autotune_ann(
     xt = jnp.asarray(rng.normal(size=(ts, d)).astype(np.float32))
     probe_k = min(_ANN_RECALL_K, int(m))
     results: dict[str, dict] = {}
+    failed: dict[str, str] = {}
     for n_tables, window in ann_candidates(n, m):
         tables = build_tables(
             xn, key=jrandom.key(0), n_tables=n_tables, n_bits=16
@@ -599,7 +627,8 @@ def autotune_ann(
             cand, _, _ = fn(xt, xn, tables)
             _, recall = matched_prefix_and_recall(cand, xt, xn, probe_k)
             recall = float(jnp.mean(recall))
-        except Exception:  # candidate unsupported on this backend
+        except Exception as e:  # noqa: BLE001 -- recorded, not dropped
+            _candidate_failed(failed, f"{n_tables}x{window}", e)
             continue
         results[f"{n_tables}x{window}"] = {
             "n_tables": n_tables, "window": window,
@@ -615,6 +644,7 @@ def autotune_ann(
     )
     entry = dict(results[winner])
     entry["candidates"] = results
+    entry["failed"] = failed
     entry["sample_t"] = ts
     with _LOCK:
         data = dict(_load(path))
@@ -717,6 +747,7 @@ def autotune_megastep(
 
     stages_name, stages_params = best_fill(n, t, backend=backend, path=path)
     timings: dict[str, float] = {}
+    failed: dict[str, str] = {}
     # donate=False: the timing loop replays the same operands, so the step
     # must not consume its accumulator buffers.
     base = make_fused_step(
@@ -725,18 +756,20 @@ def autotune_megastep(
     )
     try:
         timings["stages {}"] = _time_call(base, *args, reps=reps)
-    except Exception:
-        pass
+    except Exception as e:  # noqa: BLE001 -- recorded, not dropped
+        _candidate_failed(failed, "stages {}", e)
     for params in megakernel_candidates(n, ts, backend):
         step = make_fused_step(
             int(k), "sti", "megakernel", tuple(sorted(params.items())),
             donate=False,
         )
+        label = f"megakernel {json.dumps(params, sort_keys=True)}"
         try:
             us = _time_call(step, *args, reps=reps)
-        except Exception:  # candidate unsupported on this backend
+        except Exception as e:  # noqa: BLE001 -- recorded, not dropped
+            _candidate_failed(failed, label, e)
             continue
-        timings[f"megakernel {json.dumps(params, sort_keys=True)}"] = us
+        timings[label] = us
         if verbose:
             print(f"autotune megastep n={n} t={t} {params}: {us:.0f}us")
     if not timings:
@@ -750,6 +783,7 @@ def autotune_megastep(
         "us": timings[winner],
         "sample_t": ts,
         "candidates": timings,
+        "failed": failed,
     }
     with _LOCK:
         data = dict(_load(path))

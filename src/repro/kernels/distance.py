@@ -4,6 +4,10 @@
 (TB, NB, DB) with f32 accumulation in VMEM; row/column squared norms are
 precomputed by the wrapper (O(t d + n d)) and fused into the epilogue on the
 last reduction step.
+
+Every distance contraction here runs at `lax.Precision.HIGHEST`: the
+default TPU precision rounds f32 operands to bf16, which moves near-tied
+neighbours and breaks the exact ranks every valuation method depends on.
 """
 
 from __future__ import annotations
@@ -37,7 +41,8 @@ def candidate_sq_dists(
     """
     xt = x_test.astype(jnp.float32)
     rows = x_train.astype(jnp.float32)[cand]           # (tb, P, d)
-    cross = jnp.einsum("td,tpd->tp", xt, rows)
+    cross = jnp.einsum("td,tpd->tp", xt, rows,
+                       precision=jax.lax.Precision.HIGHEST)
     nt = jnp.sum(xt * xt, axis=-1, keepdims=True)      # (tb, 1)
     if train_norms is not None:
         nn = train_norms.astype(jnp.float32)[cand]     # (tb, P)
@@ -58,12 +63,15 @@ def _kernel(xt_ref, xn_ref, nt_ref, nn_ref, out_ref, *, n_dblocks):
     xt = xt_ref[...]  # (TB, DB)
     xn = xn_ref[...]  # (NB, DB)
     out_ref[...] += jax.lax.dot_general(
-        xt, xn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xt, xn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(pl.program_id(2) == n_dblocks - 1)
     def _epilogue():
-        d2 = nt_ref[...][:, None] - 2.0 * out_ref[...] + nn_ref[...][None, :]
+        # norms arrive as 2-D (TB, 1) / (1, NB) blocks: a 1-D block's XLA
+        # tiling does not match Mosaic's, which the TPU compiler refuses
+        d2 = nt_ref[...] - 2.0 * out_ref[...] + nn_ref[...]
         out_ref[...] = jnp.maximum(d2, 0.0)
 
 
@@ -88,8 +96,8 @@ def distance_pallas(
     tp, np_, dp = (-t) % bt, (-n) % bn, (-d) % bd
     xt = jnp.pad(x_test, ((0, tp), (0, dp)))
     xn = jnp.pad(x_train, ((0, np_), (0, dp)))
-    nt = jnp.sum(xt.astype(jnp.float32) ** 2, -1)
-    nn = jnp.sum(xn.astype(jnp.float32) ** 2, -1)
+    nt = jnp.sum(xt.astype(jnp.float32) ** 2, -1)[:, None]   # (T, 1)
+    nn = jnp.sum(xn.astype(jnp.float32) ** 2, -1)[None, :]   # (1, N)
     T, D = xt.shape
     N, _ = xn.shape
     n_dblocks = D // bd
@@ -100,8 +108,8 @@ def distance_pallas(
         in_specs=[
             pl.BlockSpec((bt, bd), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bn, bd), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bt,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((bt, 1), lambda i, j, kk: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bt, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),

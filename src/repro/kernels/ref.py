@@ -34,7 +34,7 @@ def distance_ref(x_test: jnp.ndarray, x_train: jnp.ndarray) -> jnp.ndarray:
     xn = x_train.astype(jnp.float32)
     d2 = (
         jnp.sum(xt * xt, -1, keepdims=True)
-        - 2.0 * (xt @ xn.T)
+        - 2.0 * jnp.matmul(xt, xn.T, precision=jax.lax.Precision.HIGHEST)
         + jnp.sum(xn * xn, -1)[None, :]
     )
     return jnp.maximum(d2, 0.0)

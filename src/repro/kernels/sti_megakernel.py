@@ -172,6 +172,9 @@ def _stream_sorted(xb_ref, yb_ref, xtr_ref, ytr_ref, *, n, block_n,
     xb = xb_ref[...].astype(jnp.float32)              # (tt, d)
     yb = yb_ref[...][:, 0]                            # (tt,) int
     cdtype = jnp.dtype(compute_dtype)
+    # f32 compute is f32-exact; bf16 is the explicit mixed-precision opt-in
+    precision = (jax.lax.Precision.HIGHEST if cdtype == jnp.float32
+                 else None)
     xq = xb.astype(cdtype)
     xb2 = jnp.sum(xb * xb, axis=-1, keepdims=True)    # (tt, 1) f32
     tt = xb.shape[0]
@@ -188,6 +191,7 @@ def _stream_sorted(xb_ref, yb_ref, xtr_ref, ytr_ref, *, n, block_n,
         cross = jax.lax.dot_general(
             xq, xt.astype(cdtype), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=precision,
         )                                              # (tt, bn) f32 accum
         xt2 = jnp.sum(xt * xt, axis=-1)                # (bn,) f32
         d2 = jnp.maximum(xb2 - 2.0 * cross + xt2[None, :], 0.0)

@@ -15,8 +15,8 @@ The accumulator state is threaded through the step with buffer donation
 (`donate_argnums`): each batch updates it in place, peak device memory is
 O(state + tb * n + fill_chunk * n^2) regardless of how many test batches are
 streamed, and the test set may live on the host (each batch is transferred
-as it is consumed). Donation is skipped on the CPU backend, which does not
-implement it (DESIGN.md Sec. 5).
+as it is consumed). Every backend donates, the CPU included, so the CPU
+tests drive the same in-place steps the chip runs.
 
 Every step carries a per-point validity mask folded into the contribution
 `u` (every method's update is linear in `u`, so a masked-out point
@@ -199,16 +199,16 @@ def make_fused_step(
     fill_static: tuple = (),
     distance: str = "xla",
     distance_static: tuple = (),
-    donate: Optional[bool] = None,
+    donate: bool = True,
 ) -> Callable:
     """Build the jitted fused interaction step (a thin instantiation of the
     generic `_stream_body` with the "sti"/"sii" update kernel):
 
         step(acc, diag, xb, yb, mask, x_train, y_train) -> (acc, diag)
 
-    acc (n, n) f32 and diag (n,) f32 are donated (updated in place) on
-    backends that support donation; xb/yb/mask is one (tb, d)/(tb,)/(tb,)
-    test batch (`pad_test_batch` builds the mask). The fill accumulates
+    acc (n, n) f32 and diag (n,) f32 are donated (updated in place);
+    xb/yb/mask is one (tb, d)/(tb,)/(tb,) test batch (`pad_test_batch`
+    builds the mask). The fill accumulates
     through the in-place registry form where one exists (no `acc + fill`
     temporary), and the diagonal term reuses the fill stage's `u` (gathered
     back to train coordinates) instead of re-broadcasting the (tb, n) label
@@ -222,8 +222,6 @@ def make_fused_step(
     tile shapes / compute dtype instead of fill chunking (the `distance`
     pair is ignored -- the distance stage lives inside the kernel).
     """
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
     if fill == "megakernel":
         from repro.kernels.sti_megakernel import sti_megakernel
 
@@ -254,7 +252,7 @@ def make_point_step(
     method_static: tuple = (),
     distance: str = "xla",
     distance_static: tuple = (),
-    donate: Optional[bool] = None,
+    donate: bool = True,
     fill: Optional[str] = None,
     fill_static: tuple = (),
 ) -> Callable:
@@ -264,7 +262,7 @@ def make_point_step(
         step(vec, xb, yb, mask, x_train, y_train) -> vec
 
     vec (n,) f32 accumulates the SUM of per-test-point values (finalize
-    divides by t); it is donated off-CPU exactly like the interaction
+    divides by t); it is donated exactly like the interaction
     accumulators. `method_static` is the hashable method-option tuple (e.g.
     (("weights", "rbf"),) for wknn). Same generic body, same pad/mask
     contract, same executable-per-configuration caching as the fused step.
@@ -274,8 +272,6 @@ def make_point_step(
     (`sti_megakernel.point_megakernel`) with `fill_static` carrying its
     tile shapes / compute dtype (the `distance` pair is then ignored).
     """
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
     if fill == "megakernel":
         from repro.kernels.sti_megakernel import point_megakernel
 
@@ -332,7 +328,7 @@ def make_refold_step(
     method_static: tuple = (),
     fill: str = "chunked",
     fill_static: tuple = (),
-    donate: Optional[bool] = None,
+    donate: bool = True,
 ) -> Callable:
     """Stage B of the incremental-mutation path: the jitted refold of one
     CACHED test batch under a train-slot liveness mask (tuple-state):
@@ -354,8 +350,6 @@ def make_refold_step(
         )
     else:
         body = make_refold_kernel(method, int(k), opts=dict(method_static))
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
 
     def step(state, d2, order, yb, mask, y_train, keep):
         return tuple(body(state, d2, order, yb, mask, y_train, keep))
@@ -731,7 +725,7 @@ def make_approx_point_step(
     probe: int = 0,
     probe_k: int = 0,
     method_static: tuple = (),
-    donate: Optional[bool] = None,
+    donate: bool = True,
 ) -> Callable:
     """Build the jitted approx step for a point-value method:
 
@@ -739,7 +733,7 @@ def make_approx_point_step(
             -> (vec, prefix, recall)
 
     vec (n,) f32 accumulates scatter-added candidate values (donated
-    off-CPU like the dense steps); `tables` is the `LSHTables` pytree the
+    like the dense steps); `tables` is the `LSHTables` pytree the
     session built once per train set. Per batch: candidate top-m gather ->
     label match -> candidate-space recurrence
     (`stream_kernels.make_approx_values`) -> O(tb m) scatter-add, plus the
@@ -766,8 +760,6 @@ def make_approx_point_step(
         prefix, recall = probe_fn(cand, xb, x_train)
         return vec, prefix, recall
 
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
@@ -780,7 +772,7 @@ def make_approx_interaction_step(
     window: int,
     probe: int = 0,
     probe_k: int = 0,
-    donate: Optional[bool] = None,
+    donate: bool = True,
 ) -> Callable:
     """Build the jitted approx step for "sti"/"sii" interactions:
 
@@ -832,8 +824,6 @@ def make_approx_interaction_step(
             recall,
         )
 
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
@@ -917,7 +907,7 @@ def make_sharded_step(
     distance: str = "xla",
     distance_static: tuple = (),
     axis: str = "shards",
-    donate: Optional[bool] = None,
+    donate: bool = True,
 ) -> Callable:
     """Build the jitted multi-device step over a 1-D `mesh` (axis `axis`,
     D devices). GLOBAL contract identical to the fused step:
@@ -942,7 +932,7 @@ def make_sharded_step(
 
     Row blocks are therefore complete sums over every test point seen: no
     psum is needed at finalize, only an all-gather of the rows. Accumulators
-    are donated off-CPU, exactly like the fused step. Like `make_fused_step`
+    are donated exactly like the fused step. Like `make_fused_step`
     this is a thin instantiation of the generic `_stream_body`, with the
     interaction kernel's shard_map-local update variant (`axis=` bound).
 
@@ -955,8 +945,6 @@ def make_sharded_step(
     trade (t n d / D extra FLOPs for n-free collectives and single-kernel
     locality) is the Sec. 17 design argument.
     """
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
     if fill == "megakernel":
         from repro.kernels.sti_megakernel import sti_megakernel
 
@@ -1016,7 +1004,7 @@ def make_sharded_point_step(
     distance: str = "xla",
     distance_static: tuple = (),
     axis: str = "shards",
-    donate: Optional[bool] = None,
+    donate: bool = True,
     fill: Optional[str] = None,
     fill_static: tuple = (),
 ) -> Callable:
@@ -1036,8 +1024,6 @@ def make_sharded_point_step(
     its (n/D,) vector rows with `axis_index * n/D` as the row base -- the
     psum_scatter disappears because every device folds the full batch.
     """
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
     if fill == "megakernel":
         from repro.kernels.sti_megakernel import point_megakernel
 

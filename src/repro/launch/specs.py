@@ -172,7 +172,8 @@ def sti_cell(scfg, mesh: Mesh, *, unroll: bool = False):
         # device's phi column ids.
         d2 = (
             jnp.sum(x_test * x_test, -1, keepdims=True)
-            - 2.0 * x_test @ x_train.T
+            - 2.0 * jnp.matmul(x_test, x_train.T,
+                               precision=jax.lax.Precision.HIGHEST)
             + jnp.sum(x_train * x_train, -1)[None, :]
         )
         order = jnp.argsort(d2, axis=-1, stable=True)

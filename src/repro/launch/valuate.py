@@ -33,6 +33,7 @@ from repro.core import get_method, knn_shapley_values, list_methods, loo_values
 from repro.core.methods import valid_engines
 from repro.core.session import ShardedValuationSession, ValuationSession
 from repro.data import make_circles, flip_labels
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -97,6 +98,7 @@ def main():
     ap.add_argument("--save", default=None, metavar="PATH",
                     help="persist the ValuationResult to PATH.npz + PATH.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.distributed:
         args.engine = "distributed"
     if args.resilient:

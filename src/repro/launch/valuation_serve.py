@@ -23,6 +23,7 @@ import time
 import numpy as np
 
 from repro.data import make_circles
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.valuation_service import ValuationService
 
 
@@ -61,6 +62,7 @@ def main():
                     help="recompute the final train set offline (fused "
                          "engine) and print the value drift")
     args = ap.parse_args()
+    enable_compile_cache()
 
     x, y = make_circles(args.n // 2, noise=0.08, seed=args.seed)
     xt, yt = make_circles(args.t // 2, noise=0.08, seed=args.seed + 1)

@@ -28,6 +28,7 @@ def test_distributed_sti_matches_reference():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro import compat
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs.sti_knn_paper import STIConfig
         from repro.core import sti_knn_interactions
         from repro.data import make_moons
@@ -36,7 +37,7 @@ def test_distributed_sti_matches_reference():
         n, t, k = 128, 32, 5
         x, y = make_moons(n // 2, noise=0.08, seed=0)
         xt, yt = make_moons(t // 2, noise=0.08, seed=1)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_auto_mesh((4, 2), ("data", "model"))
         scfg = STIConfig(n_train=n, feat_dim=2, k=k, test_chunk=t)
         step, _, _, _ = sti_cell(scfg, mesh)
         with compat.set_mesh(mesh):
@@ -56,6 +57,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro import compat
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs.base import ModelConfig
         from repro.launch.specs import lm_cell
         from repro.configs.base import ShapeSpec
@@ -67,7 +69,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
                           head_dim=8, d_ff=64, vocab_size=128,
                           tp_pad_heads=2, vocab_pad=32, dtype=jnp.float32)
         shape = ShapeSpec("t", seq_len=16, global_batch=8, kind="train")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_auto_mesh((4, 2), ("data", "model"))
         step, args, in_sh, out_sh = lm_cell(cfg, shape, mesh,
                                             strategy="tp_dp")
         model = build_model(cfg)
@@ -101,6 +103,7 @@ def test_fsdp_constrain_equivalence():
     run_py("""
         import jax, jax.numpy as jnp
         from repro import compat
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs.base import ModelConfig, ShapeSpec
         from repro.launch.specs import lm_cell
         from repro.models import build_model
@@ -113,7 +116,7 @@ def test_fsdp_constrain_equivalence():
                           moe_group_size=32,
                           tp_pad_heads=2, vocab_pad=32, dtype=jnp.float32)
         shape = ShapeSpec("t", seq_len=16, global_batch=8, kind="train")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_auto_mesh((4, 2), ("data", "model"))
         model = build_model(cfg)
         params = model.init(jax.random.key(0))
         toks = jax.random.randint(jax.random.key(1), (8, 16), 0, 128)
@@ -144,6 +147,7 @@ def test_dryrun_cell_on_local_mesh():
     run_py("""
         import jax, jax.numpy as jnp
         from repro import compat
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs.base import ModelConfig, ShapeSpec
         from repro.launch.specs import lm_cell
         from repro.launch.hlo_analysis import analyze_compiled, collective_bytes
@@ -154,7 +158,7 @@ def test_dryrun_cell_on_local_mesh():
                           tp_pad_heads=2, vocab_pad=32, dtype=jnp.float32,
                           scan_unroll=True)
         shape = ShapeSpec("t", seq_len=32, global_batch=8, kind="train")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_auto_mesh((4, 2), ("data", "model"))
         step, args, in_sh, out_sh = lm_cell(cfg, shape, mesh, strategy="tp_dp")
         to_named = lambda tree: jax.tree.map(
             lambda s: jax.sharding.NamedSharding(mesh, s) if s is not None else None,
@@ -178,17 +182,18 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.checkpointer import Checkpointer
+        from repro.launch.mesh import make_auto_mesh
 
         tree = {{'w': jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                  'b': jnp.ones((8,), jnp.float32)}}
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_auto_mesh((4, 2), ("data", "model"))
         sh_a = {{'w': NamedSharding(mesh_a, P("data", "model")),
                  'b': NamedSharding(mesh_a, P("model"))}}
         placed = jax.device_put(tree, sh_a)
         ck = Checkpointer(r"{tmp_path}")
         ck.save(7, placed)
 
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_auto_mesh((2, 4), ("data", "model"))
         sh_b = {{'w': NamedSharding(mesh_b, P("data", "model")),
                  'b': NamedSharding(mesh_b, P("model"))}}
         restored, step = ck.restore(tree, shardings=sh_b)

@@ -229,6 +229,17 @@ def ndev():
     return jax.device_count()
 """,
     ),
+    "R603": (
+        """
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
+""",
+        """
+from repro.launch.compile_cache import enable_compile_cache
+def main():
+    enable_compile_cache()
+""",
+    ),
     "R701": (
         """
 import numpy as np

@@ -297,7 +297,7 @@ def test_accumulate_rect_fill_matches_additive(fill, static):
 
 
 def test_resolve_rect_fill_contract():
-    # unknown names raise; pallas falls back to the XLA scan if unregistered
+    # unknown names raise (an unregistered Pallas name included)
     with pytest.raises(ValueError, match="unknown rect fill"):
         resolve_rect_fill("nope", 8, 64, 4)
     name, static = resolve_rect_fill("chunked", 8, 64, 4,
