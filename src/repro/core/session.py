@@ -130,28 +130,40 @@ class ValuationSession:
         ONE cached executable serves every slice (a stream of tiny updates
         pays the full test_batch step cost per update -- size `test_batch`
         to the arrival granularity). Returns self (chainable).
+
+        Host spans (`jax.profiler.TraceAnnotation`, written only while a
+        profiler trace is on): `session.update` around the call, with the
+        counters `points` (real test points) and `slices` (compiled steps
+        dispatched); per slice `session.pad` (slicing and padding) and
+        `session.dispatch` (placement and the step call).
         """
         from repro.kernels.sti_pipeline import pad_test_batch
 
-        xb = jnp.asarray(self._embed(jnp.asarray(x_test_batch)))
-        yb = jnp.asarray(y_test_batch)
-        if xb.ndim == 1:  # a single test point
-            xb = xb[None, :]
-            yb = jnp.reshape(yb, (1,))
-        if xb.ndim != 2 or xb.shape[1] != self.x_train.shape[1]:
-            raise ValueError(
-                f"test batch must be (b, {self.x_train.shape[1]}), "
-                f"got {xb.shape}"
-            )
-        b = xb.shape[0]
-        for start in range(0, b, self.test_batch):
-            sl = slice(start, min(start + self.test_batch, b))
-            xs, ys, mask = pad_test_batch(xb[sl], yb[sl], self.test_batch)
-            self._state = self._step(
-                self._state, *self._place_batch(xs, ys, mask),
-                self.x_train, self.y_train,
-            )
-        self._t += b
+        with jax.profiler.TraceAnnotation("session.update") as span:
+            xb = jnp.asarray(self._embed(jnp.asarray(x_test_batch)))
+            yb = jnp.asarray(y_test_batch)
+            if xb.ndim == 1:  # a single test point
+                xb = xb[None, :]
+                yb = jnp.reshape(yb, (1,))
+            if xb.ndim != 2 or xb.shape[1] != self.x_train.shape[1]:
+                raise ValueError(
+                    f"test batch must be (b, {self.x_train.shape[1]}), "
+                    f"got {xb.shape}"
+                )
+            b = xb.shape[0]
+            span.set_metadata(points=b, slices=-(-b // self.test_batch))
+            for start in range(0, b, self.test_batch):
+                with jax.profiler.TraceAnnotation("session.pad"):
+                    sl = slice(start, min(start + self.test_batch, b))
+                    xs, ys, mask = pad_test_batch(
+                        xb[sl], yb[sl], self.test_batch
+                    )
+                with jax.profiler.TraceAnnotation("session.dispatch"):
+                    self._state = self._step(
+                        self._state, *self._place_batch(xs, ys, mask),
+                        self.x_train, self.y_train,
+                    )
+            self._t += b
         return self
 
     def _place_batch(self, xs, ys, mask):

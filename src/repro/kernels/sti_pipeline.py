@@ -176,17 +176,31 @@ def _stream_body(kernel: UpdateKernel, k: int, dist_fn: Callable) -> Callable:
     (mask folded in) -> optional `superdiagonal_g` -> the method's
     registered update kernel. The per-method parts live entirely in
     `kernel` (repro.kernels.stream_kernels); everything here is shared.
+
+    Each stage runs under a `jax.named_scope` (distance, sort, rank,
+    label_gather, contrib, recurrence, update; the update kernels add
+    to_train, fill and collective inside `update`), so every device op of
+    the step carries its stage in its `op_name` metadata. The names are
+    the benchmark's layer names (bench/scopes.json): keep them stable.
     """
 
     def body(state, xb, yb, mask, x_train, y_train):
-        d2 = dist_fn(xb, x_train)                       # (tb, n) on-chip
-        order = jnp.argsort(d2, axis=-1, stable=True)   # (tb, n)
-        ranks = ranks_from_order(order)
-        match = (y_train[order] == yb[:, None]).astype(jnp.float32)
-        u = kernel.contrib(d2, order, match, mask)
-        g = (superdiagonal_g(u, k, mode=kernel.g_mode)
-             if kernel.needs_g else None)
-        return kernel.update(state, u, g, ranks, mask)
+        with jax.named_scope("distance"):
+            d2 = dist_fn(xb, x_train)                       # (tb, n) on-chip
+        with jax.named_scope("sort"):
+            order = jnp.argsort(d2, axis=-1, stable=True)   # (tb, n)
+        with jax.named_scope("rank"):
+            ranks = ranks_from_order(order)
+        with jax.named_scope("label_gather"):
+            match = (y_train[order] == yb[:, None]).astype(jnp.float32)
+        with jax.named_scope("contrib"):
+            u = kernel.contrib(d2, order, match, mask)
+        g = None
+        if kernel.needs_g:
+            with jax.named_scope("recurrence"):
+                g = superdiagonal_g(u, k, mode=kernel.g_mode)
+        with jax.named_scope("update"):
+            return kernel.update(state, u, g, ranks, mask)
 
     return body
 
@@ -315,8 +329,10 @@ def make_rank_step(
     dist_fn = _distance_fn(distance, distance_static)
 
     def rank(xb, x_train):
-        d2 = dist_fn(xb, x_train)
-        return d2, jnp.argsort(d2, axis=-1, stable=True)
+        with jax.named_scope("distance"):
+            d2 = dist_fn(xb, x_train)
+        with jax.named_scope("sort"):
+            return d2, jnp.argsort(d2, axis=-1, stable=True)
 
     return jax.jit(rank)
 
@@ -953,9 +969,10 @@ def make_sharded_step(
         def local_step(acc, diag, xb, yb, mask, x_train, y_train):
             # local views: acc (nl, n), diag (nl,), xb (tb/D, d)
             nl = acc.shape[0]
-            xb_all = jax.lax.all_gather(xb, axis, axis=0, tiled=True)
-            yb_all = jax.lax.all_gather(yb, axis, axis=0, tiled=True)
-            mask_all = jax.lax.all_gather(mask, axis, axis=0, tiled=True)
+            with jax.named_scope("collective"):
+                xb_all = jax.lax.all_gather(xb, axis, axis=0, tiled=True)
+                yb_all = jax.lax.all_gather(yb, axis, axis=0, tiled=True)
+                mask_all = jax.lax.all_gather(mask, axis, axis=0, tiled=True)
             return sti_megakernel(
                 acc, diag, xb_all, yb_all, mask_all, x_train, y_train,
                 k=int(k), mode=mode,
@@ -1032,9 +1049,10 @@ def make_sharded_point_step(
 
         def local_step(vec, xb, yb, mask, x_train, y_train):
             nl = vec.shape[0]
-            xb_all = jax.lax.all_gather(xb, axis, axis=0, tiled=True)
-            yb_all = jax.lax.all_gather(yb, axis, axis=0, tiled=True)
-            mask_all = jax.lax.all_gather(mask, axis, axis=0, tiled=True)
+            with jax.named_scope("collective"):
+                xb_all = jax.lax.all_gather(xb, axis, axis=0, tiled=True)
+                yb_all = jax.lax.all_gather(yb, axis, axis=0, tiled=True)
+                mask_all = jax.lax.all_gather(mask, axis, axis=0, tiled=True)
             return point_megakernel(
                 vec, xb_all, yb_all, mask_all, x_train, y_train,
                 method=method, k=int(k), opts=opts,

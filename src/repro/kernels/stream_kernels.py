@@ -265,13 +265,14 @@ def _interaction_factory(mode: str) -> Callable:
         if axis is None:
             def update(state, u, g, ranks, mask):
                 acc, diag = state
-                acc = accumulate_fill(acc, g, ranks, fill, fill_static)
+                with jax.named_scope("fill"):
+                    acc = accumulate_fill(acc, g, ranks, fill, fill_static)
                 # u in train coordinates is u[p, ranks[p, i]] =
                 # mask_p 1[y_i==y_p]/k: the diag term rides on the fill
                 # stage's u, masked for free.
-                diag = diag + jnp.sum(
-                    jnp.take_along_axis(u, ranks, axis=-1), axis=0
-                )
+                with jax.named_scope("to_train"):
+                    u_train = jnp.take_along_axis(u, ranks, axis=-1)
+                diag = diag + jnp.sum(u_train, axis=0)
                 return (acc, diag)
         else:
             def update(state, u, g, ranks, mask):
@@ -280,24 +281,29 @@ def _interaction_factory(mode: str) -> Callable:
                 # local views: acc (nl, n), diag (nl,), u/ranks (tb/D, n)
                 acc, diag = state
                 nl = acc.shape[0]
-                u_train = jnp.take_along_axis(u, ranks, axis=-1)
-                g_all = jax.lax.all_gather(g, axis, axis=0, tiled=True)
-                r_all = jax.lax.all_gather(ranks, axis, axis=0, tiled=True)
-                # this device's (tb, nl) row window of the global rank space
-                r_rows = rect_row_view(
-                    r_all, jax.lax.axis_index(axis) * nl, nl
-                )
-                acc = accumulate_rect_fill(
-                    acc, g_all, r_rows, r_all, fill, fill_static
-                )
+                with jax.named_scope("to_train"):
+                    u_train = jnp.take_along_axis(u, ranks, axis=-1)
+                with jax.named_scope("collective"):
+                    g_all = jax.lax.all_gather(g, axis, axis=0, tiled=True)
+                    r_all = jax.lax.all_gather(ranks, axis, axis=0,
+                                               tiled=True)
+                with jax.named_scope("fill"):
+                    # this device's (tb, nl) row window of the global rank
+                    # space
+                    r_rows = rect_row_view(
+                        r_all, jax.lax.axis_index(axis) * nl, nl
+                    )
+                    acc = accumulate_rect_fill(
+                        acc, g_all, r_rows, r_all, fill, fill_static
+                    )
                 # the diag update reduces over the test dim, so it needs
                 # only a reduce-scatter of the (n,) local partial -- O(n)
                 # bytes, not an O(tb n) gather like g/ranks, which the fill
                 # genuinely needs whole
-                diag = diag + jax.lax.psum_scatter(
-                    jnp.sum(u_train, axis=0), axis, tiled=True
-                )
-                return (acc, diag)
+                part = jnp.sum(u_train, axis=0)
+                with jax.named_scope("collective"):
+                    part = jax.lax.psum_scatter(part, axis, tiled=True)
+                return (acc, diag + part)
 
         return UpdateKernel(method, INTERACTION_STATE, True, mode,
                             contrib, update)
@@ -328,7 +334,10 @@ def _shapley_point_values(u, ranks, k, opts):
     proof only uses linearity of the utility in the per-point values."""
     from repro.core.knn_shapley import knn_shapley_from_sorted
 
-    return jnp.take_along_axis(knn_shapley_from_sorted(u, k), ranks, axis=-1)
+    with jax.named_scope("recurrence"):
+        values = knn_shapley_from_sorted(u, k)
+    with jax.named_scope("to_train"):
+        return jnp.take_along_axis(values, ranks, axis=-1)
 
 
 def _loo_point_values(u, ranks, k, opts):
@@ -336,10 +345,12 @@ def _loo_point_values(u, ranks, k, opts):
     point j < k slides the (k+1)-th neighbour in, delta = (u[j] - u[k])/k;
     points outside the window contribute zero."""
     n = u.shape[-1]
-    nxt = u[..., k:k + 1] if n > k else jnp.zeros_like(u[..., :1])
-    in_window = (jnp.arange(n) < k)[None, :]
-    delta = jnp.where(in_window, (u - nxt) / k, 0.0)
-    return jnp.take_along_axis(delta, ranks, axis=-1)
+    with jax.named_scope("recurrence"):
+        nxt = u[..., k:k + 1] if n > k else jnp.zeros_like(u[..., :1])
+        in_window = (jnp.arange(n) < k)[None, :]
+        delta = jnp.where(in_window, (u - nxt) / k, 0.0)
+    with jax.named_scope("to_train"):
+        return jnp.take_along_axis(delta, ranks, axis=-1)
 
 
 def _point_factory(contrib_fn: Callable, values_fn: Callable) -> Callable:
@@ -355,7 +366,8 @@ def _point_factory(contrib_fn: Callable, values_fn: Callable) -> Callable:
         def update(state, u, g, ranks, mask):
             part = jnp.sum(values_fn(u, ranks, k, opts), axis=0)
             if axis is not None:
-                part = jax.lax.psum_scatter(part, axis, tiled=True)
+                with jax.named_scope("collective"):
+                    part = jax.lax.psum_scatter(part, axis, tiled=True)
             return (state[0] + part,)
 
         return UpdateKernel(method, POINT_STATE, False, None,

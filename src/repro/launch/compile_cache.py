@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
 Entry points (`chip_smoke.py`, `repro.launch.valuate`,
-`repro.launch.valuation_serve`, `benchmarks.run`) call
+`repro.launch.valuation_serve`) call
 `enable_compile_cache()` once at start-up, never at import:
 
   * with `JAX_COMPILATION_CACHE_DIR` set, JAX reads that directory itself
