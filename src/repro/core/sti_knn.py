@@ -35,6 +35,7 @@ __all__ = [
     "superdiagonal_g_topm",
     "ranks_from_order",
     "ranks_from_distances",
+    "to_train",
     "pairwise_sq_dists",
     "sti_knn_interactions",
     "sti_knn_matrix_one_test",
@@ -175,18 +176,30 @@ def pairwise_sq_dists(x_test: jnp.ndarray, x_train: jnp.ndarray) -> jnp.ndarray:
     return jnp.maximum(d2, 0.0)
 
 
+def to_train(order: jnp.ndarray, *xs: jnp.ndarray) -> tuple:
+    """Bring (t, n) arrays in sorted coordinates back to train coordinates.
+
+    Returns one array per `xs`, with `out[p, i] = x[p, ranks[p, i]]`: the
+    inverse of the permutation `order` applied to each row, done as ONE sort
+    keyed by `order` that carries every `xs` as an operand (the TPU sorts;
+    it gathers and scatters element by element). Each row of `order` is a
+    permutation, so the keys are unique and the result is exact whatever
+    the sort's stability.
+    """
+    return jax.lax.sort((order, *xs), dimension=-1, is_stable=False,
+                        num_keys=1)[1:]
+
+
 def ranks_from_order(order: jnp.ndarray) -> jnp.ndarray:
     """(t, n) argsort permutation -> (t, n) integer ranks (0 = closest).
 
-    Inverts each row of `order` by scatter; shared by the streamed scan path,
-    the local pjit step, and the fused pipeline so the rank convention lives
-    in exactly one place.
+    The inverse of each row of `order`: `to_train` of the sorted positions,
+    the one rank convention of the scan path, the local pjit step and the
+    fused pipeline (whose interaction update sorts the same positions
+    together with its u).
     """
-    t, n = order.shape
-    ranks = jnp.zeros_like(order)
-    return ranks.at[jnp.arange(t)[:, None], order].set(
-        jnp.broadcast_to(jnp.arange(n, dtype=order.dtype), order.shape)
-    )
+    pos = jax.lax.broadcasted_iota(order.dtype, order.shape, order.ndim - 1)
+    return to_train(order, pos)[0]
 
 
 def ranks_from_distances(d2: jnp.ndarray) -> jnp.ndarray:

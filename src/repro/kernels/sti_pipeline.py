@@ -55,7 +55,6 @@ import jax.numpy as jnp
 from repro.core.sti_knn import (
     InteractionMode,
     pairwise_sq_dists,
-    ranks_from_order,
     resolve_fill,
     resolve_rect_fill,
     superdiagonal_g,
@@ -172,27 +171,37 @@ def _stream_body(kernel: UpdateKernel, k: int, dist_fn: Callable) -> Callable:
 
         body(state, xb, yb, mask, x_train, y_train) -> state
 
-    distance -> argsort/rank -> sorted label match -> method contribution
-    (mask folded in) -> optional `superdiagonal_g` -> the method's
-    registered update kernel. The per-method parts live entirely in
-    `kernel` (repro.kernels.stream_kernels); everything here is shared.
+    distance -> stable sort carrying the labels -> sorted label match ->
+    method contribution (mask folded in) -> optional `superdiagonal_g` ->
+    the method's registered update kernel. The per-method parts live
+    entirely in `kernel` (repro.kernels.stream_kernels); everything here is
+    shared.
 
-    Each stage runs under a `jax.named_scope` (distance, sort, rank,
+    Every permutation of a (tb, n) array is a sort operand, never a gather
+    or scatter: the one stable sort of `d2` carries the train indices
+    (`order`, exactly `jnp.argsort(d2, stable=True)`) and the train labels,
+    and the update kernels return to train coordinates with
+    `sti_knn.to_train`, one sort keyed by `order` (DESIGN.md Sec. 12).
+
+    Each stage runs under a `jax.named_scope` (distance, sort,
     label_gather, contrib, recurrence, update; the update kernels add
-    to_train, fill and collective inside `update`), so every device op of
-    the step carries its stage in its `op_name` metadata. The names are
-    the benchmark's layer names (bench/scopes.json): keep them stable.
+    rank, to_train, fill and collective inside `update`), so every device
+    op of the step carries its stage in its `op_name` metadata. The names
+    are the benchmark's layer names (bench/scopes.json): keep them stable.
     """
 
     def body(state, xb, yb, mask, x_train, y_train):
         with jax.named_scope("distance"):
             d2 = dist_fn(xb, x_train)                       # (tb, n) on-chip
         with jax.named_scope("sort"):
-            order = jnp.argsort(d2, axis=-1, stable=True)   # (tb, n)
-        with jax.named_scope("rank"):
-            ranks = ranks_from_order(order)
+            _, order, y_sorted = jax.lax.sort(              # (tb, n) each
+                (d2,
+                 jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1),
+                 jnp.broadcast_to(y_train, d2.shape)),
+                dimension=1, is_stable=True, num_keys=1,
+            )
         with jax.named_scope("label_gather"):
-            match = (y_train[order] == yb[:, None]).astype(jnp.float32)
+            match = (y_sorted == yb[:, None]).astype(jnp.float32)
         with jax.named_scope("contrib"):
             u = kernel.contrib(d2, order, match, mask)
         g = None
@@ -200,7 +209,7 @@ def _stream_body(kernel: UpdateKernel, k: int, dist_fn: Callable) -> Callable:
             with jax.named_scope("recurrence"):
                 g = superdiagonal_g(u, k, mode=kernel.g_mode)
         with jax.named_scope("update"):
-            return kernel.update(state, u, g, ranks, mask)
+            return kernel.update(state, u, g, order, mask)
 
     return body
 

@@ -18,7 +18,9 @@ rides the identical distance -> rank -> contribution -> update pipeline
     `contrib(d2, order, match, mask) -> u` (the sorted-coordinate per-point
     contribution; the validity mask is folded in here, so padded test rows
     contribute exactly zero through every method) and
-    `update(state, u, g, ranks, mask) -> state`.
+    `update(state, u, g, order, mask) -> state`, which brings what it needs
+    back to train coordinates with `sti_knn.to_train` (one sort keyed by
+    `order`; no gather or scatter).
 
 Kernels are built by registered FACTORIES (`register_update_kernel`) keyed
 by method name: a factory binds the static configuration -- k, method
@@ -48,8 +50,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.sti_knn import (
     accumulate_fill,
     accumulate_rect_fill,
-    ranks_from_order,
     superdiagonal_g,
+    to_train,
 )
 
 __all__ = [
@@ -175,9 +177,10 @@ class UpdateKernel:
     `contrib(d2, order, match, mask) -> u` maps the shared pipeline
     intermediates (squared distances, argsort order, sorted label match,
     validity mask) to the method's sorted-coordinate contribution vector;
-    `update(state, u, g, ranks, mask) -> state` folds one test batch into
-    the accumulator state (`g` is None unless `needs_g`). Both are pure and
-    trace into the enclosing jit.
+    `update(state, u, g, order, mask) -> state` folds one test batch into
+    the accumulator state (`g` is None unless `needs_g`; `order` is the
+    batch's stable argsort, each row a permutation of the train indices).
+    Both are pure and trace into the enclosing jit.
     """
 
     method: str
@@ -262,27 +265,31 @@ def _interaction_factory(mode: str) -> Callable:
         def contrib(d2, order, match, mask):
             return match * (mask / k)[:, None]
 
+        def ranks_and_u(order, u):
+            # one sort gives the ranks and u in train coordinates,
+            # u_train[p, i] = mask_p 1[y_i==y_p]/k: the diag term rides on
+            # the fill stage's u, masked for free
+            with jax.named_scope("rank"):
+                pos = jax.lax.broadcasted_iota(order.dtype, order.shape, 1)
+                return to_train(order, pos, u)
+
         if axis is None:
-            def update(state, u, g, ranks, mask):
+            def update(state, u, g, order, mask):
                 acc, diag = state
+                ranks, u_train = ranks_and_u(order, u)
                 with jax.named_scope("fill"):
                     acc = accumulate_fill(acc, g, ranks, fill, fill_static)
-                # u in train coordinates is u[p, ranks[p, i]] =
-                # mask_p 1[y_i==y_p]/k: the diag term rides on the fill
-                # stage's u, masked for free.
                 with jax.named_scope("to_train"):
-                    u_train = jnp.take_along_axis(u, ranks, axis=-1)
-                diag = diag + jnp.sum(u_train, axis=0)
+                    diag = diag + jnp.sum(u_train, axis=0)
                 return (acc, diag)
         else:
-            def update(state, u, g, ranks, mask):
+            def update(state, u, g, order, mask):
                 from repro.kernels.sti_fill import rect_row_view
 
-                # local views: acc (nl, n), diag (nl,), u/ranks (tb/D, n)
+                # local views: acc (nl, n), diag (nl,), u/order (tb/D, n)
                 acc, diag = state
                 nl = acc.shape[0]
-                with jax.named_scope("to_train"):
-                    u_train = jnp.take_along_axis(u, ranks, axis=-1)
+                ranks, u_train = ranks_and_u(order, u)
                 with jax.named_scope("collective"):
                     g_all = jax.lax.all_gather(g, axis, axis=0, tiled=True)
                     r_all = jax.lax.all_gather(ranks, axis, axis=0,
@@ -300,7 +307,8 @@ def _interaction_factory(mode: str) -> Callable:
                 # only a reduce-scatter of the (n,) local partial -- O(n)
                 # bytes, not an O(tb n) gather like g/ranks, which the fill
                 # genuinely needs whole
-                part = jnp.sum(u_train, axis=0)
+                with jax.named_scope("to_train"):
+                    part = jnp.sum(u_train, axis=0)
                 with jax.named_scope("collective"):
                     part = jax.lax.psum_scatter(part, axis, tiled=True)
                 return (acc, diag + part)
@@ -326,7 +334,7 @@ def _wknn_contrib(d2, order, match, mask, k, opts):
     return jnp.take_along_axis(w, order, axis=-1) * match * mask[:, None]
 
 
-def _shapley_point_values(u, ranks, k, opts):
+def _shapley_point_values(u, order, k, opts):
     """(tb, n) per-test-point Shapley values in TRAIN coordinates via the
     Jia et al. reverse-cumsum recurrence -- linear in `u`, so the folded
     validity mask zeroes padded rows exactly. Shared by "knn_shapley"
@@ -337,10 +345,10 @@ def _shapley_point_values(u, ranks, k, opts):
     with jax.named_scope("recurrence"):
         values = knn_shapley_from_sorted(u, k)
     with jax.named_scope("to_train"):
-        return jnp.take_along_axis(values, ranks, axis=-1)
+        return to_train(order, values)[0]
 
 
-def _loo_point_values(u, ranks, k, opts):
+def _loo_point_values(u, order, k, opts):
     """(tb, n) leave-one-out deltas in TRAIN coordinates: removing sorted
     point j < k slides the (k+1)-th neighbour in, delta = (u[j] - u[k])/k;
     points outside the window contribute zero."""
@@ -350,7 +358,7 @@ def _loo_point_values(u, ranks, k, opts):
         in_window = (jnp.arange(n) < k)[None, :]
         delta = jnp.where(in_window, (u - nxt) / k, 0.0)
     with jax.named_scope("to_train"):
-        return jnp.take_along_axis(delta, ranks, axis=-1)
+        return to_train(order, delta)[0]
 
 
 def _point_factory(contrib_fn: Callable, values_fn: Callable) -> Callable:
@@ -363,8 +371,8 @@ def _point_factory(contrib_fn: Callable, values_fn: Callable) -> Callable:
         def contrib(d2, order, match, mask):
             return contrib_fn(d2, order, match, mask, k, opts)
 
-        def update(state, u, g, ranks, mask):
-            part = jnp.sum(values_fn(u, ranks, k, opts), axis=0)
+        def update(state, u, g, order, mask):
+            part = jnp.sum(values_fn(u, order, k, opts), axis=0)
             if axis is not None:
                 with jax.named_scope("collective"):
                     part = jax.lax.psum_scatter(part, axis, tiled=True)
@@ -494,11 +502,10 @@ def compact_order(order: jnp.ndarray, keep: jnp.ndarray):
         live), indexed by train coordinate.
 
     Returns:
-      (new_order, ranks): `new_order` (tb, n) with the live entries moved
-      to the front and the dead entries to the tail, each group preserving
-      its relative order -- exactly what a stable argsort of the mutated
-      distance row produces, because dead slots hold sentinel distances
-      larger than any real one; `ranks` is its inverse permutation.
+      `new_order` (tb, n) with the live entries moved to the front and the
+      dead entries to the tail, each group preserving its relative order --
+      exactly what a stable argsort of the mutated distance row produces,
+      because dead slots hold sentinel distances larger than any real one.
     """
     keep_s = jnp.take(keep, order) > 0          # liveness in sorted coords
     live = jnp.cumsum(keep_s.astype(jnp.int32), axis=-1)
@@ -506,8 +513,7 @@ def compact_order(order: jnp.ndarray, keep: jnp.ndarray):
     n_live = live[..., -1:]
     pos = jnp.where(keep_s, live - 1, n_live + dead - 1)
     row = jnp.arange(order.shape[0], dtype=pos.dtype)[:, None]
-    new_order = jnp.zeros_like(order).at[row, pos].set(order)
-    return new_order, ranks_from_order(new_order)
+    return jnp.zeros_like(order).at[row, pos].set(order)
 
 
 _REFOLD_BUILDERS: dict[str, Callable] = {}
@@ -562,7 +568,7 @@ def _masked_refold_builder(kernel: UpdateKernel, k: int) -> Callable:
 
     def refold(state, d2, order, yb, mask, y_train, keep):
         d2 = jnp.where(keep[None, :] > 0, d2, jnp.float32(SENTINEL_D2 * 1e10))
-        new_order, ranks = compact_order(order, keep)
+        new_order = compact_order(order, keep)
         match = (jnp.take(y_train, new_order) == yb[:, None]).astype(
             jnp.float32
         )
@@ -572,7 +578,7 @@ def _masked_refold_builder(kernel: UpdateKernel, k: int) -> Callable:
             if kernel.needs_g
             else None
         )
-        return kernel.update(state, u, g, ranks, mask)
+        return kernel.update(state, u, g, new_order, mask)
 
     return refold
 

@@ -122,6 +122,37 @@ def test_sharded_ragged_stream_and_checkpoint_restore():
     """)
 
 
+def test_sharded_matches_fused_over_ties_and_sentinels():
+    """Every stream method sharded over 4 devices against the fused step,
+    on a train set with many exact distance ties and two sentinel slots:
+    each device's sort carries the labels and sorts back by `order` as the
+    fused step does, so the two agree to float summation order."""
+    run_py(_PROBLEM + """
+    from repro.core.session import ShardedValuationSession, ValuationSession
+    from repro.kernels.stream_kernels import SENTINEL_COORD, SENTINEL_LABEL
+
+    rng = np.random.default_rng(4)
+    n, t = 24, 13
+    x = rng.integers(-2, 3, size=(n, 2)).astype(np.float32)
+    y = rng.integers(0, 3, size=n).astype(np.int32)
+    for slot in (3, n - 5):
+        x[slot], y[slot] = SENTINEL_COORD, SENTINEL_LABEL
+    xt = rng.integers(-2, 3, size=(t, 2)).astype(np.float32)
+    yt = rng.integers(0, 3, size=t).astype(np.int32)
+    for method in ("sti", "sii", "knn_shapley", "wknn", "loo"):
+        kw = dict(k=3, mode=method, test_batch=8)
+        sharded = ShardedValuationSession(x, y, shards=4, **kw)
+        assert sharded.shards == 4
+        a = sharded.update(xt, yt).finalize()
+        b = ValuationSession(x, y, **kw).update(xt, yt).finalize()
+        got, want = (np.asarray(r.phi if r.phi is not None
+                                else r.point_values) for r in (a, b))
+        assert np.isfinite(want).all()
+        np.testing.assert_allclose(got, want, atol=1e-6)
+        print("ok", method, float(np.abs(got - want).max()))
+    """, devices=4)
+
+
 def test_sharded_engine_via_method_registry():
     """get_method("sti")(..., engine="sharded") matches the fused engine and
     carries shard provenance in the result metadata."""

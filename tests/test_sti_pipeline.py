@@ -214,6 +214,102 @@ def test_fused_single_executable_across_ragged_batches():
     assert step._cache_size() == 1
 
 
+# ------------------------------------------------- permutations by sorting
+STREAM_METHODS = ["sti", "sii", "knn_shapley", "wknn", "loo"]
+
+
+def _scatter_ranks(order):
+    """The inverse permutation by scatter, as the step computed it before
+    it sorted instead."""
+    t, n = order.shape
+    return jnp.zeros_like(order).at[jnp.arange(t)[:, None], order].set(
+        jnp.broadcast_to(jnp.arange(n, dtype=order.dtype), order.shape))
+
+
+def _tied_problem(n=21, tb=6, dim=2, seed=4):
+    """Integer coordinates from a small grid, so many squared distances tie
+    exactly, two sentinel train slots (a removed point's coordinates and
+    label), and a padded last test row."""
+    from repro.kernels.stream_kernels import SENTINEL_COORD, SENTINEL_LABEL
+
+    rng = np.random.default_rng(seed)
+    x = rng.integers(-2, 3, size=(n, dim)).astype(np.float32)
+    y = rng.integers(0, 3, size=n).astype(np.int32)
+    for slot in (3, n - 5):
+        x[slot], y[slot] = SENTINEL_COORD, SENTINEL_LABEL
+    xt = rng.integers(-2, 3, size=(tb, dim)).astype(np.float32)
+    yt = rng.integers(0, 3, size=tb).astype(np.int32)
+    mask = np.ones(tb, np.float32)
+    mask[-1] = 0.0
+    return tuple(map(jnp.asarray, (x, y, xt, yt, mask)))
+
+
+def _kernel(method, k, n, tb):
+    from repro.kernels.stream_kernels import make_update_kernel
+
+    fill, static = resolve_fill("auto", n, tb)
+    return make_update_kernel(method, k, fill=fill, fill_static=static)
+
+
+@pytest.mark.parametrize("method", STREAM_METHODS)
+def test_step_permutes_by_sort_exactly(method, monkeypatch):
+    """The step's sort carries the train indices and labels, and `to_train`
+    sorts back by `order`: over exact ties and sentinel slots, `order` is
+    the stable argsort, its `to_train` inverse the scatter's ranks, every
+    `to_train` the gather through those ranks, and the state one step
+    folds matches the gather-and-scatter step to 1 f32 ulp."""
+    import dataclasses
+
+    from repro.core.sti_knn import pairwise_sq_dists, to_train
+    from repro.kernels import sti_pipeline, stream_kernels
+
+    k, (x, y, xt, yt, mask) = 3, _tied_problem()
+    n, tb = x.shape[0], xt.shape[0]
+    kernel = _kernel(method, k, n, tb)
+    spy = dataclasses.replace(kernel, update=lambda state, u, g, order, m: (
+        kernel.update(state, u, g, order, m), order))
+    body = sti_pipeline._stream_body(spy, k, pairwise_sq_dists)
+    state0 = tuple(
+        jnp.asarray(np.random.default_rng(1).normal(size=s), jnp.float32)
+        for s in kernel.spec.shapes(n))
+    got, order = jax.jit(body)(state0, xt, yt, mask, x, y)
+
+    d2 = pairwise_sq_dists(xt, x)
+    want_order = jnp.argsort(d2, axis=-1, stable=True)
+    assert len(np.unique(np.asarray(d2))) < d2.size // 2   # many exact ties
+    np.testing.assert_array_equal(np.asarray(order), np.asarray(want_order))
+    ranks = _scatter_ranks(order)
+    np.testing.assert_array_equal(np.asarray(ranks_from_order(order)),
+                                  np.asarray(ranks))
+    vals = jnp.asarray(np.random.default_rng(2).normal(size=order.shape),
+                       jnp.float32)
+    for xs in (vals, order):
+        np.testing.assert_array_equal(
+            np.asarray(to_train(order, xs)[0]),
+            np.asarray(jnp.take_along_axis(xs, ranks, axis=-1)))
+
+    # the same step the gather-and-scatter way: labels gathered through
+    # `order`, values gathered back through the scattered ranks
+    monkeypatch.setattr(stream_kernels, "to_train", lambda o, *xs: tuple(
+        jnp.take_along_axis(v, _scatter_ranks(o), axis=-1) for v in xs))
+    old_kernel = _kernel(method, k, n, tb)
+
+    def old_body(state, xb, yb, m, x_train, y_train):
+        d2 = pairwise_sq_dists(xb, x_train)
+        order = jnp.argsort(d2, axis=-1, stable=True)
+        match = (y_train[order] == yb[:, None]).astype(jnp.float32)
+        u = old_kernel.contrib(d2, order, match, m)
+        g = (superdiagonal_g(u, k, mode=old_kernel.g_mode)
+             if old_kernel.needs_g else None)
+        return old_kernel.update(state, u, g, order, m)
+
+    want = jax.jit(old_body)(state0, xt, yt, mask, x, y)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all()
+        assert np.abs(a - b).max() <= np.spacing(np.abs(b).max()), method
+
+
 # ------------------------------------------------------- accumulate fills
 @pytest.mark.parametrize("fill,static", [
     ("chunked", (("chunk", 2),)),

@@ -21,11 +21,13 @@ from repro.kernels.sti_pipeline import prepare_stream_step
 
 N, D, K, TB = 24, 4, 3, 8
 
-POINT_SCOPES = {"distance", "sort", "rank", "label_gather", "contrib",
+# point methods bring their values back to train coordinates with one sort
+# (`to_train`) and never need the ranks; sti sorts the ranks out with u
+POINT_SCOPES = {"distance", "sort", "label_gather", "contrib",
                 "recurrence", "to_train", "update"}
 METHOD_SCOPES = {
     "knn_shapley": POINT_SCOPES,
-    "sti": POINT_SCOPES | {"fill"},
+    "sti": POINT_SCOPES | {"rank", "fill"},
 }
 
 
