@@ -20,10 +20,15 @@ One chip:
      update, then `finalize()`, `checkpoint()` and `restore()`.
 
 --four-chips (and nothing else): sti through a `ShardedValuationSession`
-over 4 chips at the paper cell, n=65,536, d=768, k=5: every chip must hold
-an (n/4, n) row block, and 16 rows of each block are checked as in A. The
-same session at n=8,192 is compared against the fused single-chip step to
-<= 1e-5.
+over 4 chips at the sizes of the benchmark's `sti-tinyimagenet`
+configuration (n=100,000, d=768, 200 classes, k=5, data from
+`bench/data.py`): every chip must hold an (n/4, n) row block. Two batches
+are folded and `finalize()` assembles phi on the host: no chip's
+`peak_bytes_in_use` may rise during it, and its 32 sampled rows and
+diagonal must match the benchmark's plain reference divided by t within
+the configuration's `rows_gap` and `diag_gap` limits. The host's peak RSS
+is printed. The same session at n=8,192 is compared against the fused
+single-chip step to <= 1e-5.
 
 Every session resolves `fill="auto"`, `distance="auto"` with
 `autotune=False` and no autotune cache, and the run checks that they
@@ -363,17 +368,30 @@ def phase_references(seed: int, n: int = 2048, d: int = 16, k: int = 5,
     _log(f"C peak device memory {_peak_gib(jax.devices()[0])}")
 
 
-def phase_four_chips(seed: int, n: int = 65536, d: int = 768, k: int = 5,
-                     tb: int = 256, batches: int = 4,
+def phase_four_chips(seed: int, config: str = "sti-tinyimagenet",
+                     tb: int = 256, batches: int = 2,
                      n_ref: int = 8192) -> None:
-    """Sharded sti over four chips, and its reference at n_ref."""
+    """Sharded sti over four chips at a benchmark configuration's sizes,
+    finalized on the host, and its reference at n_ref."""
+    import resource
+
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from repro.core.session import ShardedValuationSession, ValuationSession
 
     devs = jax.devices()
     _check(len(devs) == 4, f"--four-chips needs 4 devices, found {len(devs)}")
+
+    sys.path.insert(0, str(ROOT / "bench"))
+    import catalog
+    import data
+
+    cat = catalog.Catalog(ROOT)
+    cfg = cat.config(config)
+    n, d, k, shards = (int(cfg[key]) for key in ("n", "d", "k", "shards"))
+    _check(shards == 4, f"{config} is sharded over {shards}, not 4")
 
     # reference: the same session at n_ref against the fused 1-chip step
     _expect_defaults(n_ref, d, tb, rows=n_ref // 4)
@@ -393,33 +411,62 @@ def phase_four_chips(seed: int, n: int = 65536, d: int = 768, k: int = 5,
     _check(err <= TOL, f"sharded vs fused {err:.3e} > {TOL}")
     del sharded, fused, x, y, xt, yt
 
-    # the paper cell: (n/4, n) f32 row blocks, 4 GiB per chip
-    _, dist = _expect_defaults(n, d, tb, rows=n // 4)
-    x, y = _gaussian(seed, n, d, classes=10)
-    xt, yt = _gaussian(seed + 1, tb * batches, d, classes=10)
+    # the configuration's size: (n/4, n) f32 row blocks, one per chip
+    _expect_defaults(n, d, tb, rows=n // 4)
+    x, y, xb, yb = data.mixture(seed, n=n, pool=cfg["test_pool"], d=d,
+                                classes=cfg["classes"],
+                                sep=cfg["class_sep"], tb=tb)
     sess = ShardedValuationSession(x, y, shards=4, k=k, mode="sti",
                                    test_batch=tb, fill="auto",
                                    distance="auto", autotune=False)
     _check(sess.shards == 4, f"sharded over {sess.shards}")
     _log(f"4 resolved session: {sess._resolved}")
-    _timed_updates(sess, xt, yt, tb, f"4 sharded sti n={n} d={d}")
-    acc, diag = sess._state
-    shards = acc.addressable_shards
-    shapes = sorted({tuple(s.data.shape) for s in shards})
-    owners = {s.device for s in shards}
-    _log(f"4 acc {acc.shape}: {len(shards)} shards of {shapes} on "
+    t0 = time.perf_counter()
+    for b in range(batches):
+        sess.update(xb[b], yb[b])
+    jax.block_until_ready(sess._state)
+    _log(f"4 sharded sti n={n} d={d}: {batches} batches of {tb} "
+         f"(compile included) in {time.perf_counter() - t0:.3f} s")
+    acc = sess._state[0]
+    blocks = acc.addressable_shards
+    shapes = sorted({tuple(s.data.shape) for s in blocks})
+    owners = {s.device for s in blocks}
+    _log(f"4 acc {acc.shape}: {len(blocks)} shards of {shapes} on "
          f"{len(owners)} devices")
     _check(len(owners) == 4 and shapes == [(n // 4, n)],
            f"each chip holds one ({n // 4}, {n}) block")
-    # efficiency on the row blocks: finalize would gather 16 GiB per chip
-    _check(bool(jnp.all(jnp.isfinite(diag))), "finite diagonal")
-    _efficiency_gap("4", acc, diag, sess.t_seen,
-                    _v_full(x, y, xt, yt, k, dist))
-    # 16 rows at the start of every chip's block: each row offset is right
-    rows = [c * (n // 4) + i for c in range(4) for i in range(16)]
-    _check_rows("4", acc, rows, x, y, xt, yt, k, dist, tb)
-    for dev in devs:
-        _log(f"4 peak device memory {dev}: {_peak_gib(dev)}")
+    del acc, blocks
+    check = cat.method("sti").Check(cfg, seed, 4)
+    want = check.reference(range(batches), x, y, xb, yb)
+
+    # finalize: phi is assembled on the host, block by block
+    def peaks():
+        return [int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                for dev in devs]
+
+    before = peaks()
+    t0 = time.perf_counter()
+    phi = sess.finalize().phi
+    secs = time.perf_counter() - t0
+    after = peaks()
+    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    _log(f"4 finalize: phi {phi.shape} {type(phi).__name__} in {secs:.3f} s; "
+         f"host peak RSS {rss / 2**30:.3f} GiB")
+    for dev, lo, hi in zip(devs, before, after):
+        _log(f"4 peak device memory {dev}: {lo / 2**30:.4f} GiB before "
+             f"finalize, {hi / 2**30:.4f} GiB after")
+    _check(all(hi <= lo for lo, hi in zip(before, after)),
+           "finalize raised a chip's peak_bytes_in_use")
+    t = sess.t_seen
+    got = {"rows": check._offdiag(phi[check.rows]),
+           "diag": np.diagonal(phi).astype(np.float64)}
+    numbers, _ = check.numbers(got, {key: v / t for key, v in want.items()})
+    limits = cfg["check"]["limits"]
+    for name, value in numbers.items():
+        _log(f"4 finalize {name} vs the reference / t: {value:.3e} "
+             f"(limit {limits[name]})")
+        _check(value <= float(limits[name]),
+               f"{name} {value:.3e} > {limits[name]}")
 
 
 def main() -> int:

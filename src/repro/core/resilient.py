@@ -167,7 +167,7 @@ class ResilientValuationSession:
     @property
     def shards(self) -> int:
         """Current device count of the wrapped session (1 = single)."""
-        return getattr(self._inner, "shards", 1)
+        return self._inner.shards
 
     @property
     def t_seen(self) -> int:
@@ -374,8 +374,8 @@ class ResilientValuationSession:
             "config": np.asarray(json.dumps(self._config())),
             "scalars": {"seq": np.int64(self._folded),
                         "t": np.int64(self._inner._t)},
-            "state": {nm: a for nm, a in zip(
-                self._inner._spec.names, self._inner._gathered_state())},
+            "state": dict(zip(self._inner._spec.names,
+                              self._inner._host_state())),
         }
 
     def checkpoint(self) -> None:

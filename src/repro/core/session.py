@@ -31,11 +31,12 @@ continues exactly where the saved one stopped.
 `ShardedValuationSession` is the multi-device form (DESIGN.md Sec. 10/12):
 the test stream is row-sharded over a 1-D device mesh and the state is
 sharded per its spec layout -- (n/D, n) row blocks for the interaction
-matrix, (n/D,) row shards for vectors -- gathered only at `finalize()`.
-Checkpoints are written as the dense host arrays, so a stream checkpointed
-under D devices restores under any device count (including 1: the session
-silently falls back to the single-device step when only one shard is
-usable).
+matrix, (n/D,) row shards for vectors. `finalize()` and `checkpoint()` copy
+each block to the host (`_host_state`), so no device ever holds the whole
+(n, n) array. Checkpoints are written as the dense host arrays, so a stream
+checkpointed under D devices restores under any device count (including 1:
+the session silently falls back to the single-device step when only one
+shard is usable).
 """
 
 from __future__ import annotations
@@ -58,11 +59,18 @@ __all__ = [
 ]
 
 
+def _nbytes(arrays: dict) -> int:
+    """Bytes of the arrays that are a dict's values."""
+    return sum(int(a.nbytes) for a in arrays.values())
+
+
 class ValuationSession:
     """Streaming valuation of any registered method against a fixed
     training set (see module docstring)."""
 
     _ENGINE = "session"
+    shards = 1          # devices the step runs on
+    _gather_bytes = 0   # bytes a device receives through a step's gathers
 
     def __init__(self, x_train, y_train, *, k: int = 5, mode: str = "sti",
                  test_batch: int = 256, fill: str = "auto",
@@ -134,8 +142,10 @@ class ValuationSession:
         Host spans (`jax.profiler.TraceAnnotation`, written only while a
         profiler trace is on): `session.update` around the call, with the
         counters `points` (real test points) and `slices` (compiled steps
-        dispatched); per slice `session.pad` (slicing and padding) and
-        `session.dispatch` (placement and the step call).
+        dispatched), and on a step over several devices `shards` (their
+        number) and `gather_bytes` (bytes each device receives through one
+        step's all-gathers, from shapes); per slice `session.pad` (slicing
+        and padding) and `session.dispatch` (placement and the step call).
         """
         from repro.kernels.sti_pipeline import pad_test_batch
 
@@ -151,7 +161,11 @@ class ValuationSession:
                     f"got {xb.shape}"
                 )
             b = xb.shape[0]
-            span.set_metadata(points=b, slices=-(-b // self.test_batch))
+            counters = {"points": b, "slices": -(-b // self.test_batch)}
+            if self.shards > 1:
+                counters.update(shards=self.shards,
+                                gather_bytes=self._gather_bytes)
+            span.set_metadata(**counters)
             for start in range(0, b, self.test_batch):
                 with jax.profiler.TraceAnnotation("session.pad"):
                     sl = slice(start, min(start + self.test_batch, b))
@@ -191,22 +205,36 @@ class ValuationSession:
         self.y_train = y
 
     # ------------------------------------------------------------- results
-    def _gathered_state(self) -> tuple:
-        """Hook: the state as whole host-addressable arrays (sharded
-        sessions re-place their shards as replicated)."""
-        return self._state
+    def _host_state(self) -> tuple:
+        """The state as writable host numpy arrays, copied block by block
+        from each array's shards (one copy of a replicated one): a row-
+        sharded (n, n) array is assembled on the host and never on a
+        device, into an array of its own that finalize may divide in place
+        (`np.asarray` of the whole array returns a read-only one). JAX
+        keeps each shard's host copy cached on the state until the next
+        step replaces it."""
+        out = []
+        for a in self._state:
+            host = np.empty(a.shape, a.dtype)
+            for shard in a.addressable_shards:
+                if shard.replica_id == 0:
+                    host[shard.index] = np.asarray(shard.data)
+            out.append(host)
+        return tuple(out)
 
     def _finalize_arrays(self) -> dict:
         """Hook: the finalized `ValuationResult` array kwargs (the approx
         session densifies its sparse pair accumulator here)."""
-        return self._spec.result_arrays(self._gathered_state(), self._t)
+        return self._spec.result_arrays(self._host_state(), self._t)
 
     def finalize(self) -> ValuationResult:
         """Snapshot the running mean as a `ValuationResult` (the session
         remains live; later updates refine the next finalize)."""
         if self._t == 0:
             raise ValueError("no test points seen: call update() first")
-        arrays = self._finalize_arrays()
+        with jax.profiler.TraceAnnotation("session.finalize") as span:
+            arrays = self._finalize_arrays()
+            span.set_metadata(bytes=_nbytes(arrays))
         meta = {
             "method": self.mode,
             "mode": self.mode,
@@ -234,8 +262,9 @@ class ValuationSession:
 
         State is saved as dense host arrays under the spec's stable names
         ("acc"/"diag" for interaction modes, "vec" for point-value modes;
-        sharded sessions gather their shards first), so a checkpoint
-        restores under any device count.
+        assembled from the shards on the host, `_host_state`), so a
+        checkpoint restores under any device count. Host span
+        `session.checkpoint`, counter `bytes` (the host arrays written).
 
         The write is ATOMIC: bytes go to a `.tmp` sibling which is fsync'd
         and then renamed over the final path, so a preemption mid-write can
@@ -253,28 +282,27 @@ class ValuationSession:
             "method_opts": self.method_opts,
             **self._extra_config(),
         }
-        arrays = self._checkpoint_arrays()
         out = base.with_suffix(".npz")
         tmp = base.with_suffix(".npz.tmp")
-        try:
-            with open(tmp, "wb") as f:
-                np.savez_compressed(
-                    f, config=np.asarray(json.dumps(cfg)), **arrays
-                )
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, out)
-        finally:
-            tmp.unlink(missing_ok=True)
+        with jax.profiler.TraceAnnotation("session.checkpoint") as span:
+            arrays = self._checkpoint_arrays()
+            span.set_metadata(bytes=_nbytes(arrays))
+            try:
+                with open(tmp, "wb") as f:
+                    np.savez_compressed(
+                        f, config=np.asarray(json.dumps(cfg)), **arrays
+                    )
+                    f.flush()
+                    os.fsync(f.fileno())
+                os.replace(tmp, out)
+            finally:
+                tmp.unlink(missing_ok=True)
         return out
 
     def _checkpoint_arrays(self) -> dict:
         """Hook: the named host arrays a checkpoint persists (the approx
         session appends its sparse pair-accumulator arrays)."""
-        return {
-            name: np.asarray(a)
-            for name, a in zip(self._spec.names, self._gathered_state())
-        }
+        return dict(zip(self._spec.names, self._host_state()))
 
     @classmethod
     def _restore_opts(cls, cfg: dict) -> dict:
@@ -353,8 +381,8 @@ class ValuationSession:
 class ShardedValuationSession(ValuationSession):
     """Multi-device streaming valuation: test stream row-sharded over a 1-D
     mesh, accumulator state sharded per its spec layout ((n/D, n) row blocks
-    for the interaction matrix, (n/D,) rows for vectors), gathered only at
-    finalize/checkpoint.
+    for the interaction matrix, (n/D,) rows for vectors), copied to the host
+    block by block at finalize/checkpoint.
 
     `shards=None` uses every local device (clamped to a divisor of n via
     `repro.distributed.sharding.shard_count`); `shards=1` -- or a single-
@@ -370,7 +398,6 @@ class ShardedValuationSession(ValuationSession):
         self._requested_shards = shards
         self._requested_mesh = mesh
         self.mesh = None
-        self.shards = 1
         super().__init__(x_train, y_train, **opts)
 
     def _build(self, fill, fill_params, distance, distance_params, autotune):
@@ -401,7 +428,10 @@ class ShardedValuationSession(ValuationSession):
                            autotune)
             self._resolved = dict(self._resolved, shards=1)
             return
-        from repro.kernels.sti_pipeline import prepare_sharded_stream_step
+        from repro.kernels.sti_pipeline import (
+            prepare_sharded_stream_step,
+            step_gather_bytes,
+        )
 
         d = int(self.x_train.shape[1])
         self._step, self._resolved, self.mesh, self._spec = (
@@ -414,6 +444,8 @@ class ShardedValuationSession(ValuationSession):
             )
         )
         self.test_batch = int(self._resolved["test_batch"])
+        self._gather_bytes = step_gather_bytes(self._spec, self._resolved,
+                                               n, d)
         self._state = self._spec.init(
             n, self._spec.shardings(self.mesh, self.mesh.axis_names[0])
         )
@@ -461,14 +493,6 @@ class ShardedValuationSession(ValuationSession):
         self._state = tuple(
             jax.device_put(a, s) for a, s in zip(arrays, shardings)
         )
-
-    def _gathered_state(self) -> tuple:
-        if self.mesh is None:
-            return self._state
-        from repro.distributed.sharding import replicated_sharding
-
-        rep = replicated_sharding(self.mesh)
-        return tuple(jax.device_put(a, rep) for a in self._state)
 
     def _extra_config(self) -> dict:
         return {"shards": self.shards}
@@ -655,7 +679,7 @@ class ApproxValuationSession(ValuationSession):
         if self._pairs is None:
             return super()._finalize_arrays()
         return {
-            "phi": self._pairs.to_dense(np.asarray(self._state[0]), self._t)
+            "phi": self._pairs.to_dense(self._host_state()[0], self._t)
         }
 
     def _approx_meta(self) -> dict:

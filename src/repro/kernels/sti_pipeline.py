@@ -37,10 +37,10 @@ are the multi-device form (DESIGN.md Sec. 10): the test stream is row-sharded
 over a 1-D `compat.shard_map` mesh, the accumulator is sharded by ROW BLOCKS
 of the (n, n) matrix — (n/D, n) per device, so peak accumulator memory falls
 as 1/D — and the only per-step collective is an all-gather of the small
-(tb, n) g/rank tables; the row blocks are complete sums, so finalize needs
-one all-gather and no psum over the matrix. Vector-state methods shard the
-(n,) accumulator the same way the interaction diagonal always was
-(`make_sharded_point_step`): the per-step collective is one O(n)
+(tb, n) g/rank tables; the row blocks are complete sums, so finalize copies
+each block to the host and needs no collective over the matrix. Vector-state
+methods shard the (n,) accumulator the same way the interaction diagonal
+always was (`make_sharded_point_step`): the per-step collective is one O(n)
 psum_scatter, never anything n-squared.
 """
 
@@ -84,6 +84,7 @@ __all__ = [
     "make_sharded_point_step",
     "prepare_sharded_step",
     "prepare_sharded_stream_step",
+    "step_gather_bytes",
     "sharded_sti_knn_interactions",
     "stream_point_values",
     "resolve_distance",
@@ -956,7 +957,8 @@ def make_sharded_step(
          resolves them).
 
     Row blocks are therefore complete sums over every test point seen: no
-    psum is needed at finalize, only an all-gather of the rows. Accumulators
+    collective is needed at finalize, which copies each block to the host
+    (`ValuationSession._host_state`). Accumulators
     are donated exactly like the fused step. Like `make_fused_step`
     this is a thin instantiation of the generic `_stream_body`, with the
     interaction kernel's shard_map-local update variant (`axis=` bound).
@@ -975,7 +977,7 @@ def make_sharded_step(
 
         params = dict(fill_static)
 
-        def local_step(acc, diag, xb, yb, mask, x_train, y_train):
+        def step(acc, diag, xb, yb, mask, x_train, y_train):
             # local views: acc (nl, n), diag (nl,), xb (tb/D, d)
             nl = acc.shape[0]
             with jax.named_scope("collective"):
@@ -994,7 +996,7 @@ def make_sharded_step(
             int(k), _distance_fn(distance, distance_static),
         )
 
-        def local_step(acc, diag, xb, yb, mask, x_train, y_train):
+        def step(acc, diag, xb, yb, mask, x_train, y_train):
             # local views: acc (nl, n), diag (nl,), xb (tb/D, d), mask
             # (tb/D,)
             return body((acc, diag), xb, yb, mask, x_train, y_train)
@@ -1003,8 +1005,9 @@ def make_sharded_step(
 
     from repro import compat
 
+    # the step program is `jit_step`, as the single-device steps' are
     step = compat.shard_map(
-        local_step,
+        step,
         mesh=mesh,
         in_specs=(
             P(axis, None),   # acc row blocks
@@ -1056,7 +1059,7 @@ def make_sharded_point_step(
         params = dict(fill_static)
         opts = dict(method_static)
 
-        def local_step(vec, xb, yb, mask, x_train, y_train):
+        def step(vec, xb, yb, mask, x_train, y_train):
             nl = vec.shape[0]
             with jax.named_scope("collective"):
                 xb_all = jax.lax.all_gather(xb, axis, axis=0, tiled=True)
@@ -1074,7 +1077,7 @@ def make_sharded_point_step(
             int(k), _distance_fn(distance, distance_static),
         )
 
-        def local_step(vec, xb, yb, mask, x_train, y_train):
+        def step(vec, xb, yb, mask, x_train, y_train):
             # local views: vec (n/D,), xb (tb/D, d), mask (tb/D,)
             return body((vec,), xb, yb, mask, x_train, y_train)[0]
 
@@ -1082,8 +1085,9 @@ def make_sharded_point_step(
 
     from repro import compat
 
+    # the step program is `jit_step`, as the single-device steps' are
     step = compat.shard_map(
-        local_step,
+        step,
         mesh=mesh,
         in_specs=(
             P(axis),         # vec rows
@@ -1258,6 +1262,26 @@ def prepare_sharded_stream_step(
         "test_batch": int(tb),
     }
     return _vector_state(inner), resolved, mesh, spec
+
+
+def step_gather_bytes(spec: "AccumulatorSpec", resolved: dict, n: int,
+                      d: int) -> int:
+    """Bytes each device receives through one sharded step's all-gathers,
+    from shapes, for a step `prepare_sharded_stream_step` resolved: every
+    device gets the other D-1 devices' (tb/D)-row slices of what is
+    gathered -- g (f32) and ranks (i32), 8 n bytes a test point, for the
+    interaction methods; the test rows (f32 features, i32 label, f32 mask)
+    for the megakernel; nothing for the point methods, whose one exchange
+    is a reduce-scatter of an (n,) partial."""
+    shards = int(resolved["shards"])
+    per = int(resolved["test_batch"]) // shards
+    if resolved["fill"] == "megakernel":
+        row = 4 * d + 8
+    elif spec.kind == "interaction":
+        row = 8 * n
+    else:
+        return 0
+    return (shards - 1) * per * row
 
 
 def sharded_sti_knn_interactions(

@@ -45,6 +45,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.sti_knn import (
@@ -147,21 +148,18 @@ class AccumulatorSpec:
         """Finalize a state of t accumulated test points into the
         `ValuationResult` array kwargs: {"phi": ...} for interaction state
         (running mean, diagonal = main terms), {"point_values": ...} for
-        vector state."""
+        vector state.
+
+        Interaction state must be writable host numpy arrays (what
+        `ValuationSession._host_state` returns): `acc` is divided by t in
+        place and its diagonal overwritten with diag / t, so the result is
+        the only (n, n) buffer and no device ever holds a second one."""
         if self.kind == "interaction":
-            return {"phi": _finalize_interactions(*state, t)}
+            acc, diag = state
+            acc /= t
+            np.fill_diagonal(acc, diag / t)
+            return {"phi": acc}
         return {"point_values": state[0] / t}
-
-
-@jax.jit
-def _finalize_interactions(acc, diag, t):
-    """acc / t with its diagonal replaced by diag / t, as one elementwise
-    program whose output is the only new (n, n) buffer. (Op by op, or as a
-    diagonal scatter, `acc / t` is a second one: 12 GiB with `acc` at
-    n=32,768, more than a v5e chip holds.)"""
-    rows = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
-    return jnp.where(rows == cols, (diag / t)[:, None], acc / t)
 
 
 INTERACTION_STATE = AccumulatorSpec(

@@ -324,3 +324,109 @@ def test_sharded_rejects_indivisible_n():
 
     with pytest.raises(ValueError, match="row shards"):
         prepare_sharded_step(7, 3, 2, mesh=_FakeMesh())
+
+
+# --------------------------------------------- results that stay row-sharded
+_HOST_RESULTS = _PROBLEM + """
+    import tempfile, os
+    from repro.core.session import ShardedValuationSession, ValuationSession
+
+    assert jax.device_count() == 4
+    n, k, tb = 64, 5, 4
+    # one test point per shard and step: both engines add the same values
+    # in the same order (with more, the sharded diagonal's reduce-scatter
+    # sums the test points in another order than the fused sum)
+    x, y, xt, yt = problem(n, 10, seed=11, classes=3)
+
+    puts = []
+    real_put = jax.device_put
+
+    def spy(a, *args, **kw):
+        puts.append(tuple(np.shape(a)))
+        return real_put(a, *args, **kw)
+
+    jax.device_put = spy
+
+    def on_host(call, sess):
+        # no device array of a state array's full shape is placed or left
+        # behind by `call`, besides the state the session steps to: the
+        # state leaves the devices block by block
+        shapes = {tuple(a.shape) for a in sess._state}
+        before = {id(a) for a in jax.live_arrays()}
+        puts.clear()
+        out = call()
+        placed = [p for p in puts if p in shapes]
+        made = [tuple(a.shape) for a in jax.live_arrays()
+                if id(a) not in before and tuple(a.shape) in shapes
+                and not any(a is s for s in sess._state)]
+        assert not placed and not made, (placed, made)
+        return out
+
+    def bits(res):
+        a = res.phi if res.phi is not None else res.point_values
+        assert isinstance(a, np.ndarray), type(a)
+        return a.view(np.uint32)
+
+    def same(a, b):
+        assert np.array_equal(bits(a), bits(b))
+"""
+
+
+@pytest.mark.parametrize("method", ["sti", "sii", "knn_shapley"])
+def test_sharded_finalize_and_checkpoint_stay_on_the_host(method):
+    """A 4-shard session's finalize() and checkpoint -> restore ->
+    finalize() equal the fused session's bit for bit, and neither places
+    nor leaves on a device an array of the state's full shape."""
+    run_py(_HOST_RESULTS + f"""
+    method = {method!r}
+    fused = ValuationSession(x, y, k=k, mode=method, test_batch=tb)
+    sharded = ShardedValuationSession(x, y, k=k, mode=method,
+                                      test_batch=tb, shards=4)
+    assert sharded.shards == 4
+    for sess in (fused, sharded):
+        sess.update(xt[:6], yt[:6]).update(xt[6:], yt[6:])
+    want = fused.finalize()
+    same(on_host(sharded.finalize, sharded), want)
+    with tempfile.TemporaryDirectory() as td:
+        ck = on_host(lambda: sharded.checkpoint(os.path.join(td, "ck")),
+                     sharded)
+        back = ShardedValuationSession.restore(ck, x, y)
+        assert back.shards == 4 and back.t_seen == 10
+        same(on_host(back.finalize, back), want)
+        same(ValuationSession.restore(ck, x, y).finalize(), want)
+    # finalize is a snapshot: the sessions fold on and agree again
+    for sess in (fused, sharded, back):
+        sess.update(xt[:4], yt[:4])
+    want = fused.finalize()
+    same(sharded.finalize(), want)
+    same(back.finalize(), want)
+    print("ok", method)
+    """, devices=4)
+
+
+def test_resilient_sharded_session_checkpoints_on_the_host():
+    """A ResilientValuationSession over 4 shards checkpoints every batch
+    and finalizes with no full-shape device array, and its result and the
+    one restored from its checkpoints equal the fused session's bit for
+    bit."""
+    run_py(_HOST_RESULTS + """
+    from repro.core.resilient import ResilientValuationSession
+
+    fused = ValuationSession(x, y, k=k, mode="sti", test_batch=tb)
+    fused.update(xt[:6], yt[:6]).update(xt[6:], yt[6:])
+    want = fused.finalize()
+    with tempfile.TemporaryDirectory() as td:
+        rs = ResilientValuationSession(
+            x, y, ckpt_dir=td, mode="sti", k=k, test_batch=tb, shards=4,
+            ckpt_every=1, async_checkpoint=False)
+        assert rs.shards == 4
+        inner = rs.inner
+        on_host(lambda: rs.update(xt[:6], yt[:6]).update(xt[6:], yt[6:]),
+                inner)
+        assert rs.resilience_summary()["checkpoint_steps"] == [1, 2]
+        same(on_host(rs.finalize, inner), want)
+        back = ResilientValuationSession.restore(td, x, y, shards=4)
+        assert back.shards == 4 and back.t_seen == 10
+        same(back.finalize(checkpoint=False), want)
+    print("ok")
+    """, devices=4)
