@@ -1,6 +1,7 @@
 """Device time of the per-test sort and rank per step and chip: the stable
-argsort, the rank scatter (`ranks_from_order`) and the gathers through
-`order`, as `layers.json` classifies their ops."""
+sort of the distances that carries the labels along, and the sorts keyed by
+`order` back to train coordinates (sti's ranks and u, the point methods'
+values), as `layers.json` classifies their ops."""
 
 LAYER = "kernels/sti_pipeline sort and rank"
 UNIT = "ms"

@@ -19,7 +19,9 @@ traffic mix, per-layer metrics and reference check are files found by name
      hides stalls of the shared host (PERF.md: with one step or none in
      flight, 4 of 48 runs lost 0.08 to 1.1 s of device time to them). A
      compilation inside the window fails the run (`WindowCompiled`): every
-     shape is warmed in set-up;
+     shape is warmed in set-up. After each step waited for, the check
+     moves what it keeps to the host (`poll`), so the peak read next is
+     the program's;
   3. reads the peak device memory, takes what the check needs from the
      state, frees it, and compares with the plain reference.
 
@@ -223,6 +225,8 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
                 break
             with spans("block_until_ready"):
                 queue.popleft().block_until_ready()
+            with spans("check"):
+                check.poll()
             done += 1
             step_s = (time.perf_counter() - t0) / done
     elapsed = time.perf_counter() - t0

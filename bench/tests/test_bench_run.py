@@ -7,6 +7,7 @@ import os
 import shutil
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -67,12 +68,36 @@ def run(monkeypatch):
     return harness
 
 
+def _count_window_polls(run, monkeypatch) -> list:
+    """Records each call of the check's `poll` made by the harness itself
+    (the knn_shapley check also polls from `before_step`)."""
+    calls = []
+    method = run.catalog.Catalog.method
+
+    def counting(self, name):
+        base = method(self, name).Check
+
+        class Check(base):
+            def poll(self):
+                if sys._getframe(1).f_code.co_name == "run_cell":
+                    calls.append(1)
+                super().poll()
+
+        return types.SimpleNamespace(Check=Check)
+
+    monkeypatch.setattr(run.catalog.Catalog, "method", counting)
+    return calls
+
+
 @pytest.mark.parametrize("cell", ["tiny-sti.mini", "tiny-knn.mini"])
-def test_new_cell_runs_correct_on_cpu(checkout, run, cell):
+def test_new_cell_runs_correct_on_cpu(checkout, run, monkeypatch, cell):
     import reference
 
+    polls = _count_window_polls(run, monkeypatch)
     res = run.run_cell(checkout, cell, 2**33 + 11, 0.3, False,
                        require_tpu=False, controls=reference.CONTROLS)
+    # the window polls the check once after each step it waits for
+    assert len(polls) == res["attempted"]
     assert res["correct"] is True
     assert list(res)[-1] == "checks"
     # f32 rounding alone: a step's contribution sums 16 test points, so it
